@@ -21,6 +21,8 @@ def set_host_devices(n: int) -> None:
     assert "jax" not in sys.modules, "set_host_devices must run before jax import"
     os.environ["XLA_FLAGS"] = (os.environ.get("_REPRO_BASE_XLA", "")
                                + f" --xla_force_host_platform_device_count={n}")
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
 
 def time_call(fn: Callable[[], object], iters: int = 30, warmup: int = 5) -> float:

@@ -44,7 +44,7 @@ def main(iters=30, out="experiments/bench/collective_sweep.csv",
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core import (PlanCache, allgatherv_init, breakeven,
                             metadata as md, patterns, reduce_scatter_init)
     from repro.launch.mesh import make_mesh

@@ -39,7 +39,7 @@ def main(iters=20, n_elems=1 << 20, out="experiments/bench/compression.csv",
     import numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.launch.mesh import make_host_mesh
     from repro.parallel import compression
 

@@ -7,7 +7,8 @@ dispatch running through the persistent alltoallv engine, on a
 
 import os
 
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count=8")
 
 import sys
 import tempfile

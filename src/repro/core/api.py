@@ -34,7 +34,7 @@ Variant decision tree
                    bottleneck, rows are large, or flat-fence padding blows
                    up under skew; see ``benchmarks/hierarchy_sweep.py``.
   ragged           ``lax.ragged_all_to_all`` (real-TPU only): no capacity
-                   padding at all, gated on ``compat.HAS_RAGGED_ALL_TO_ALL``.
+                   padding at all; XLA:CPU cannot execute it.
 
 For embedding inside a larger shard_map program (MoE dispatch), use
 ``plan.embed()`` — the traced epoch body driven by the same INIT-baked

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .. import compat
 from ..obs.spans import TRACER
 from ..parallel import wirecodec
 from . import breakeven
@@ -39,6 +38,13 @@ from ._init_stats import INIT_STATS
 from .plan import AlltoallvPlan, AlltoallvSpec, PlanCache
 
 
+def ragged_alltoall_executes() -> bool:
+    """True where ``lax.ragged_all_to_all`` can execute: it lowers on XLA:TPU
+    only (XLA:CPU has no ragged-all-to-all emitter), so the ``variant="auto"``
+    candidate set folds ragged in exactly under this predicate."""
+    return jax.default_backend() == "tpu"
+
+
 def candidate_variants(spec: AlltoallvSpec, mesh) -> list[str]:
     """Variants worth measuring for this spec's pattern.
 
@@ -46,10 +52,8 @@ def candidate_variants(spec: AlltoallvSpec, mesh) -> list[str]:
     linearized pair); the leader-combined hierarchy needs a genuine
     (outer, inner) factorization AND baked metadata (its two-stage tables
     have no in-graph twins, so A/B mode excludes it).  ragged joins the set
-    only where it can actually run — ``lax.ragged_all_to_all`` exists in
-    this jax (``compat.HAS_RAGGED_ALL_TO_ALL``) and the backend can execute
-    it (XLA:TPU; CPU has no ragged emitter) — and only on a single-axis
-    exchange (the ragged spec takes one mesh axis).
+    only where the backend can execute it (``ragged_alltoall_executes``)
+    and only on a single-axis exchange (the ragged spec takes one mesh axis).
 
     The spec's collective further restricts the set: reduce-scatter has no
     leader-combined hierarchy (combining distinct routed blocks vs summing)
@@ -59,7 +63,7 @@ def candidate_variants(spec: AlltoallvSpec, mesh) -> list[str]:
     if (len(spec.axis) == 2 and int(mesh.shape[spec.axis[0]]) > 1
             and spec.baked_metadata):
         cands.append("fence_hierarchy")
-    if len(spec.axis) == 1 and compat.ragged_alltoall_executes():
+    if len(spec.axis) == 1 and ragged_alltoall_executes():
         cands.append("ragged")
     supported = patterns.get(spec.collective).supported_variants
     return [v for v in cands if v in supported]

@@ -23,7 +23,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from ..compat import shard_map
+from jax import shard_map
 from . import variants
 
 

@@ -58,7 +58,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
+from jax import shard_map
 from ..obs.spans import TRACER
 from ..parallel import wirecodec
 from . import metadata as md

@@ -382,11 +382,6 @@ def ragged_exchange(
     each target's window.  The window operand is donated by the plan, so the
     same device buffer is reused epoch over epoch (window reuse).
     """
-    if not hasattr(jax.lax, "ragged_all_to_all"):
-        raise NotImplementedError(
-            "jax.lax.ragged_all_to_all is unavailable in this jax release; "
-            "the ragged variant needs a newer jax (gate callers on "
-            "repro.compat.HAS_RAGGED_ALL_TO_ALL)")
     return jax.lax.ragged_all_to_all(
         x, window, input_offsets, send_sizes, output_offsets, recv_sizes, axis_name=axis
     )

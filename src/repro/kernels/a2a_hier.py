@@ -6,17 +6,17 @@ combined ragged slab per (source group, target group) pair it owns.  The
 unfused path materializes the packed slab buffer in HBM (gather) and then
 ``ppermute``s it round by round; this kernel fuses the two:
 
-  * epoch OPEN — a semaphore barrier with exactly the leaders I exchange
-    with this epoch (my put target and my put source for every active
-    macro-round), guaranteeing their slab windows are re-exposed before any
-    put lands — the ``MPI_Win_fence`` hazard, scoped to the leader group
-    instead of all P ranks.
+  * epoch OPEN — a barrier on the collective barrier semaphore with exactly
+    the leaders I exchange with this epoch (my put target and my put source
+    for every active macro-round), guaranteeing their slab windows are
+    re-exposed before any put lands — the ``MPI_Win_fence`` hazard, scoped
+    to the leader group instead of all P ranks.
   * per macro-round, the slab's rows are gathered from the stage-1 recv
     buffer (HBM) straight into a VMEM staging tile via the INIT-baked,
-    scalar-prefetched index map, masked, and put remotely from VMEM.  Two
-    staging tiles alternate so the *local gather* of round m overlaps the
-    *inter-leader put* of round m-1 — the local work of group pair g hides
-    behind the wire time of group pair g-1.
+    scalar-prefetched index map (padding rows are zeroed in VMEM),
+    and put remotely from VMEM.  Two staging tiles alternate so the *local
+    gather* of round m overlaps the *inter-leader put* of round m-1 — the
+    local work of group pair g hides behind the wire time of group pair g-1.
   * epoch CLOSE — drain my sends, then wait for the slabs my inbound
     leaders put into my window (send/recv DMA semaphores).
 
@@ -40,15 +40,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
+from .a2a_fence import device_id
+from .gather_rows import from_words, gather_into, masked_index, to_words
 
 
-def _device_id(mesh_axes, axis, target):
-    return tuple(target if a == axis else jax.lax.axis_index(a) for a in mesh_axes)
-
-
-def _hier_leader_kernel(idx_ref, s1_ref, valid_ref, out_ref, scratch, row_sems,
-                        send_sem, recv_sem, barrier_sem,
+def _hier_leader_kernel(idx_ref, s1_ref, out_ref, scratch, row_sems,
+                        send_sem, recv_sem,
                         *, p_outer, p_inner, round_caps, round_offs,
                         outer_axis, inner_axis, mesh_axes):
     o = jax.lax.axis_index(outer_axis)
@@ -65,6 +62,7 @@ def _hier_leader_kernel(idx_ref, s1_ref, valid_ref, out_ref, scratch, row_sems,
         return valid, dst, src
 
     # ---- epoch OPEN: barrier with this epoch's exchange partners ----
+    barrier_sem = pltpu.get_barrier_semaphore()
     n_valid = jnp.zeros((), jnp.int32)
     for m in active:
         valid, dst, src = ring(m)
@@ -72,35 +70,18 @@ def _hier_leader_kernel(idx_ref, s1_ref, valid_ref, out_ref, scratch, row_sems,
         @pl.when(valid)
         def _():
             pltpu.semaphore_signal(barrier_sem, 1,
-                                   device_id=_device_id(mesh_axes, outer_axis, dst),
+                                   device_id=device_id(mesh_axes, outer_axis, dst),
                                    device_id_type=pltpu.DeviceIdType.MESH)
             pltpu.semaphore_signal(barrier_sem, 1,
-                                   device_id=_device_id(mesh_axes, outer_axis, src),
+                                   device_id=device_id(mesh_axes, outer_axis, src),
                                    device_id_type=pltpu.DeviceIdType.MESH)
         n_valid = n_valid + valid.astype(jnp.int32)
     pltpu.semaphore_wait(barrier_sem, 2 * n_valid)
 
     def gather_slab(m, slot):
-        """Slab m's rows: stage-1 recv buffer (HBM) -> scratch[slot], masked."""
-        cap, off = round_caps[m], round_offs[m]
-
-        def start_row(k, _):
-            s = idx_ref[off + k]
-            pltpu.make_async_copy(
-                s1_ref.at[s], scratch.at[slot, k], row_sems.at[k]).start()
-            return _
-
-        def wait_row(k, _):
-            s = idx_ref[off + k]
-            pltpu.make_async_copy(
-                s1_ref.at[s], scratch.at[slot, k], row_sems.at[k]).wait()
-            return _
-
-        jax.lax.fori_loop(0, cap, start_row, 0)
-        jax.lax.fori_loop(0, cap, wait_row, 0)
-        mask = valid_ref[pl.ds(off, cap), :]
-        scratch[slot, pl.ds(0, cap)] = (
-            scratch[slot, pl.ds(0, cap)] * mask.astype(scratch.dtype))
+        """Slab m's rows: stage-1 recv buffer (HBM) -> scratch[slot]."""
+        gather_into(idx_ref, round_offs[m], round_caps[m], s1_ref,
+                    scratch.at[slot], row_sems)
 
     def remote_put(i):
         """Descriptor for active round i's put (recreated for the waits)."""
@@ -111,7 +92,7 @@ def _hier_leader_kernel(idx_ref, s1_ref, valid_ref, out_ref, scratch, row_sems,
             src_ref=scratch.at[i % 2, pl.ds(0, cap)],
             dst_ref=out_ref.at[pl.ds(off, cap)],
             send_sem=send_sem.at[i % 2], recv_sem=recv_sem,
-            device_id=_device_id(mesh_axes, outer_axis, dst),
+            device_id=device_id(mesh_axes, outer_axis, dst),
             device_id_type=pltpu.DeviceIdType.MESH)
 
     # ---- pipelined gather+put rounds: gather m overlaps put m-1 ----
@@ -161,33 +142,30 @@ def rma_hier_leader_exchange(
 ) -> jax.Array:
     """Fused slab-gather + inter-leader puts; returns the stage-2 recv
     layout ``[total_s2, F]`` (call inside shard_map over ``mesh_axes``)."""
-    f = s1_recv.shape[1]
     max_cap = max(cap for cap in round_caps if cap > 0)
-    valid2d = s2_valid.astype(jnp.int32).reshape(total_s2, 1)
+    words = to_words(s1_recv)
+    w = words.shape[2]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(1,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),                   # s1 recv in HBM
-            pl.BlockSpec((total_s2, 1), lambda g, idx: (0, 0)),  # valid in VMEM
-        ],
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],     # s1 recv in HBM
         out_specs=pl.BlockSpec(memory_space=pl.ANY),
         scratch_shapes=[
-            pltpu.VMEM((2, max_cap, f), s1_recv.dtype),   # staging slabs
+            pltpu.VMEM((2, max_cap, 1, w), jnp.uint32),   # staging slabs
             pltpu.SemaphoreType.DMA((max_cap,)),          # per-row gathers
             pltpu.SemaphoreType.DMA((2,)),                # send, per slot
             pltpu.SemaphoreType.DMA,                      # recv
-            pltpu.SemaphoreType.REGULAR,                  # leader barrier
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_hier_leader_kernel, p_outer=p_outer,
                           p_inner=p_inner, round_caps=tuple(round_caps),
                           round_offs=tuple(round_offs),
                           outer_axis=outer_axis, inner_axis=inner_axis,
                           mesh_axes=mesh_axes),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((total_s2, f), s1_recv.dtype),
-        compiler_params=tpu_compiler_params(collective_id=11),
+        out_shape=jax.ShapeDtypeStruct((total_s2, 1, w), jnp.uint32),
+        compiler_params=pltpu.CompilerParams(collective_id=11),
         interpret=interpret,
-    )(s2_idx.astype(jnp.int32), s1_recv, valid2d)
+    )(masked_index(s2_idx, s2_valid), words)
+    return from_words(out.reshape(total_s2, w), s1_recv.dtype)

@@ -1,10 +1,12 @@
 """Jitted public wrappers around the Pallas kernels.
 
-Handles TPU tiling constraints (128-lane feature padding, tile-divisible row
-counts), feature-shape flattening, and backend selection: on a real TPU the
-kernels compile natively; on CPU (this container, and unit tests) they run
-under the TPU interpreter (``interpret=True`` executes the kernel body,
-including inter-chip remote DMAs via shard_map, on host).
+Handles TPU tiling constraints (lane padding of the 32-bit word view,
+8-row-multiple row counts), feature-shape flattening, and backend selection:
+on a TPU the kernels always compile natively.  On CPU — the unit-test path
+only — the local gather kernels run under the HLO interpreter, the remote-DMA
+kernels under the TPU-semantics interpreter (``pltpu.InterpretParams``, which
+executes inter-chip DMAs and semaphores across shard_map's host devices), and
+``fused_unpack_matmul`` takes its jnp form.
 """
 
 from __future__ import annotations
@@ -13,6 +15,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
 import numpy as np
 
@@ -23,31 +26,29 @@ from . import a2a_hier as _hier
 from . import a2a_lock as _lock
 
 LANE = 128
+ROW_TILE = 8          # Mosaic's row tile for 32-bit words
 
 
-def _interpret_default() -> bool:
+def _on_cpu() -> bool:
     return jax.default_backend() == "cpu"
 
 
-def _interpret_default_rma():
+def _interpret_rma():
     """Remote DMAs/semaphores need the TPU interpreter, not the HLO one."""
-    if jax.default_backend() != "cpu":
-        return False
-    from repro.compat import tpu_interpret_params
-    params = tpu_interpret_params()
-    if params is None:
-        raise NotImplementedError(
-            "this jax release has no TPU-semantics Pallas interpreter "
-            "(pltpu.InterpretParams); RMA kernels can only run on real TPU "
-            "hardware here — gate callers on repro.compat.has_tpu_interpret()")
-    return params
+    return pltpu.InterpretParams() if _on_cpu() else False
 
 
-def _pick_tile(n: int) -> int:
-    for t in (64, 32, 16, 8):
-        if n % t == 0:
-            return t
-    return 1
+def _row_tile(n: int) -> tuple[int, int]:
+    """``(tile_rows, n_pad)``: ``n`` padded to the 8-row tile, and the
+    largest tile of at most 64 rows that divides it."""
+    n_pad = -(-n // ROW_TILE) * ROW_TILE
+    return next(t for t in (64, 32, 16, 8) if n_pad % t == 0), n_pad
+
+
+def _pad_rows(a: jax.Array, n_pad: int) -> jax.Array:
+    """Pad the last axis of an index/mask array with zeros to ``n_pad``."""
+    extra = n_pad - a.shape[-1]
+    return jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, extra)]) if extra else a
 
 
 def _flatten_features(x: jax.Array) -> tuple[jax.Array, tuple[int, ...]]:
@@ -56,8 +57,9 @@ def _flatten_features(x: jax.Array) -> tuple[jax.Array, tuple[int, ...]]:
 
 
 def _pad_lanes(x2d: jax.Array) -> tuple[jax.Array, int]:
+    """Pad features so the kernels' 32-bit word view fills whole lanes."""
     f = x2d.shape[1]
-    pad = (-f) % LANE
+    pad = (-f) % (LANE * _gather.words_per_lane(x2d.dtype))
     if pad:
         x2d = jnp.pad(x2d, ((0, 0), (0, pad)))
     return x2d, f
@@ -65,14 +67,16 @@ def _pad_lanes(x2d: jax.Array) -> tuple[jax.Array, int]:
 
 def _masked_gather(x: jax.Array, idx: jax.Array, valid: jax.Array,
                    interpret=None) -> jax.Array:
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = _on_cpu() if interpret is None else interpret
+    n = idx.shape[0]
+    tile, n_pad = _row_tile(n)
     x2d, feat = _flatten_features(x)
     x2d, f0 = _pad_lanes(x2d)
     out = _gather.gather_rows(
-        x2d, idx.astype(jnp.int32), valid,
-        tile_rows=_pick_tile(idx.shape[0]), interpret=interpret)
-    out = out[:, :f0]
-    return out.reshape((idx.shape[0],) + feat)
+        x2d, _pad_rows(idx.astype(jnp.int32), n_pad), _pad_rows(valid, n_pad),
+        tile_rows=tile, interpret=interpret)
+    out = out[:n, :f0]
+    return out.reshape((n,) + feat)
 
 
 def pack(x: jax.Array, src_idx: jax.Array, valid: jax.Array,
@@ -90,7 +94,7 @@ def unpack(buckets: jax.Array, src_idx: jax.Array, valid: jax.Array,
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
 def _kernel_unpack_matmul(interp_key, x2d, idx, valid, w):
     return _gmm.gather_matmul(
-        x2d, idx, valid, w, tile_rows=_pick_tile(idx.shape[1]),
+        x2d, idx, valid, w, tile_rows=_row_tile(idx.shape[1])[0],
         interpret=(interp_key == "interpret"))
 
 
@@ -119,6 +123,21 @@ _kernel_unpack_matmul.defvjp(_kernel_unpack_matmul_fwd,
                              _kernel_unpack_matmul_bwd)
 
 
+def unpack_matmul_ref(x2d: jax.Array, idx: jax.Array, w: jax.Array,
+                      valid: jax.Array, scales: jax.Array | None = None
+                      ) -> jax.Array:
+    """jnp form of :func:`fused_unpack_matmul` (its CPU path): gather,
+    optional per-row dequant, mask, then one einsum per expert."""
+    e, n = idx.shape
+    h = jnp.take(x2d, idx.reshape(-1), axis=0).reshape(e, n, -1)
+    h = h.astype(w.dtype)
+    if scales is not None:
+        h = h * jnp.take(scales, idx.reshape(-1), axis=0
+                         ).reshape(e, n, 1).astype(w.dtype)
+    h = h * valid.reshape(e, n, 1).astype(h.dtype)
+    return jnp.einsum("end,edf->enf", h, w)
+
+
 def fused_unpack_matmul(x: jax.Array, idx: jax.Array, w: jax.Array,
                         valid: jax.Array | None = None,
                         scales: jax.Array | None = None,
@@ -129,8 +148,8 @@ def fused_unpack_matmul(x: jax.Array, idx: jax.Array, w: jax.Array,
     matmul reads rows straight out of the receive buffer via the INIT-baked
     unpack table.  On TPU the Pallas kernel (``kernels/gather_matmul.py``)
     DMAs each row tile into VMEM and feeds the MXU — the regrouped
-    ``[recv_rows, D]`` intermediate never lands in HBM.  Off-TPU the
-    semantically identical jnp gather + einsum runs instead (the per-row
+    ``[recv_rows, D]`` intermediate never lands in HBM.  On CPU (the test
+    path) the semantically identical jnp gather + einsum runs instead (the per-row
     interpreted DMAs would be orders slower than the reference einsum, and
     the jnp form is natively differentiable); the kernel path carries a
     custom VJP whose backward is the jnp scatter-add transpose.
@@ -138,8 +157,7 @@ def fused_unpack_matmul(x: jax.Array, idx: jax.Array, w: jax.Array,
     ``scales`` ([rows, 1], a wire codec's per-row dequant factors) folds
     the decode into the gather: ``x`` may be narrow wire rows (int8/fp8)
     and each gathered row is scaled as it is read — the decoded
-    ``[recv_rows, D]`` fp32 buffer never materializes on the fallback
-    path.  The kernel path pre-scales ``x`` instead (in-kernel dequant is
+    ``[recv_rows, D]`` fp32 buffer never materializes on the CPU path.  The kernel path pre-scales ``x`` instead (in-kernel dequant is
     future work), which still skips one full-buffer round trip vs
     decode-then-gather.
     """
@@ -149,16 +167,9 @@ def fused_unpack_matmul(x: jax.Array, idx: jax.Array, w: jax.Array,
         valid = jnp.ones((e, n), jnp.int32)
     x2d, _ = _flatten_features(x)
     if interpret is None:
-        if jax.default_backend() != "cpu":
-            interpret = False
-        else:
-            h = jnp.take(x2d, idx.reshape(-1), axis=0).reshape(e, n, -1)
-            h = h.astype(w.dtype)
-            if scales is not None:
-                h = h * jnp.take(scales, idx.reshape(-1), axis=0
-                                 ).reshape(e, n, 1).astype(w.dtype)
-            h = h * valid.reshape(e, n, 1).astype(h.dtype)
-            return jnp.einsum("end,edf->enf", h, w)
+        if _on_cpu():
+            return unpack_matmul_ref(x2d, idx, w, valid, scales)
+        interpret = False
     if scales is not None or x2d.dtype != w.dtype:
         x2d = x2d.astype(w.dtype)
         if scales is not None:
@@ -167,9 +178,11 @@ def fused_unpack_matmul(x: jax.Array, idx: jax.Array, w: jax.Array,
     f0 = w.shape[2]
     wp = jnp.pad(w.astype(x2d.dtype),
                  ((0, 0), (0, x2d.shape[1] - d0), (0, (-f0) % LANE)))
-    out = _kernel_unpack_matmul("interpret" if interpret else "compile",
-                                x2d, idx, valid.astype(jnp.int32), wp)
-    return out[:, :, :f0]
+    n_pad = _row_tile(n)[1]
+    out = _kernel_unpack_matmul(
+        "interpret" if interpret else "compile", x2d, _pad_rows(idx, n_pad),
+        _pad_rows(valid.astype(jnp.int32), n_pad), wp)
+    return out[:, :n, :f0]
 
 
 def fused_pack_alltoallv(x: jax.Array, src_idx: jax.Array, valid: jax.Array,
@@ -183,22 +196,8 @@ def fused_pack_alltoallv(x: jax.Array, src_idx: jax.Array, valid: jax.Array,
     never written to HBM, removing one full buffer write+read of padded
     traffic per epoch versus ``pack`` followed by ``rma_alltoallv``.
 
-    On environments that can neither compile the kernel (no TPU) nor
-    interpret its remote DMAs (jax without ``pltpu.InterpretParams``) this
-    falls back to the semantically identical jnp pack + ``lax.all_to_all``
-    reference so plans with ``pack_impl='fused'`` stay runnable everywhere.
     """
-    if interpret is None:
-        if jax.default_backend() == "cpu":
-            from repro.compat import tpu_interpret_params
-            interpret = tpu_interpret_params()
-            if interpret is None:
-                from repro.core import variants
-                packed = variants.pack_rows(x, src_idx, valid)
-                return jax.lax.all_to_all(
-                    packed, axis, split_axis=0, concat_axis=0, tiled=True)
-        else:
-            interpret = False
+    interpret = _interpret_rma() if interpret is None else interpret
     x2d, feat = _flatten_features(x)
     x2d, f0 = _pad_lanes(x2d)
     out = _fence.rma_alltoallv_fence_fused(
@@ -220,23 +219,8 @@ def fused_hier_leader_exchange(s1_recv: jax.Array, s2_src: jax.Array,
     scalar-prefetched) and puts it to the partner leader — the packed slab
     buffer never lands in HBM, and the gather of macro-round m overlaps the
     put of round m-1.
-
-    On environments that can neither compile the kernel (no TPU) nor
-    interpret its remote DMAs this falls back to the semantically identical
-    jnp gather + per-round ``ppermute`` leader epoch, so hierarchy plans
-    with ``pack_impl='fused'`` stay runnable everywhere.
     """
-    if interpret is None:
-        if jax.default_backend() == "cpu":
-            from repro.compat import tpu_interpret_params
-            interpret = tpu_interpret_params()
-            if interpret is None:
-                from repro.core import variants
-                return variants.stage2_leader_ppermute(
-                    s1_recv, s2_src, s2_valid, schedule,
-                    (outer_axis, inner_axis))
-        else:
-            interpret = False
+    interpret = _interpret_rma() if interpret is None else interpret
     x2d, feat = _flatten_features(s1_recv)
     x2d, f0 = _pad_lanes(x2d)
     out = _hier.rma_hier_leader_exchange(
@@ -258,7 +242,7 @@ def rma_alltoallv(packed: jax.Array, *, variant: str, p: int, capacity: int,
     variant="fence": barrier-bracketed epoch, all puts overlapped.
     variant="lock":  passive-target, serialized pairwise epochs.
     """
-    interpret = _interpret_default_rma() if interpret is None else interpret
+    interpret = _interpret_rma() if interpret is None else interpret
     x2d, feat = _flatten_features(packed)
     x2d, f0 = _pad_lanes(x2d)
     kern = {"fence": _fence.rma_alltoallv_fence,

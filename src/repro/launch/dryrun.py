@@ -27,7 +27,8 @@ import os
 # Before ANY jax import (the module docstring above is the only earlier
 # statement, and it touches nothing): jax locks the device count at first
 # init, so the fake-device override must already be in the environment.
-os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count="
+os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                           + " --xla_force_host_platform_device_count="
                            + os.environ.get("REPRO_DRYRUN_DEVICES", "512"))
 
 import argparse
@@ -35,6 +36,9 @@ import json
 import sys
 import time
 import traceback
+
+# The production meshes below are TPU v5e pods; roofline terms use its peaks.
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 
 def _mem_analysis_dict(compiled):
@@ -55,14 +59,6 @@ def _mem_analysis_dict(compiled):
     if not out:
         out = {"repr": str(ma)}
     return out
-
-
-def _cost_analysis_dict(compiled) -> dict:
-    """jax >= 0.5 returns a flat dict; 0.4.x wraps it in a one-element list."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
 
 
 def dataclasses_replace_wire(colls, wire_corrected: float):
@@ -88,7 +84,7 @@ def _costs_of(cfg, shape, mesh, bundle_kw=None):
     kw = dict(bundle_kw or {})
     kw.pop("n_micro", None)   # shallow cost variants are exact at n_micro=1
     compiled = steps_mod.make_bundle(cfg, shape, mesh, **kw).compile()
-    cost = {k: float(v) for k, v in _cost_analysis_dict(compiled).items()
+    cost = {k: float(v) for k, v in (compiled.cost_analysis() or {}).items()
             if isinstance(v, (int, float))}
     colls = parse_collectives(compiled.as_text())
     return (cost.get("flops", 0.0), cost.get("bytes accessed", 0.0),
@@ -162,7 +158,7 @@ def run_cell(cfg, shape, mesh, mesh_name, out_dir, perf_variant=None,
         bundle_kw["n_micro"] = n_micro_used
 
     mem = _mem_analysis_dict(compiled)
-    cost = {k: float(v) for k, v in _cost_analysis_dict(compiled).items()
+    cost = {k: float(v) for k, v in (compiled.cost_analysis() or {}).items()
             if isinstance(v, (int, float))}
     colls = parse_collectives(compiled.as_text())
     chips = 1
@@ -178,6 +174,7 @@ def run_cell(cfg, shape, mesh, mesh_name, out_dir, perf_variant=None,
     colls_corrected = dataclasses_replace_wire(colls, wire_c)
     roof = roofline_mod.analyze(cfg, shape, mesh_name, chips, cost_corrected,
                                 colls_corrected,
+                                device_kind=TARGET_DEVICE_KIND,
                                 peak_memory=(mem or {}).get("temp_size_in_bytes"))
 
     record = {

@@ -11,14 +11,8 @@ import numpy as np
 
 
 def _make_mesh(shape, axes):
-    # jax >= 0.5 exposes jax.sharding.AxisType and make_mesh takes
-    # axis_types; older versions (this container ships 0.4.x) have neither —
-    # every axis is Auto by default there, so the plain call is equivalent.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(tuple(shape), tuple(axes),
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -47,6 +47,8 @@ def main(argv=None):
                    help="write a Prometheus text-format metrics snapshot "
                         "to PATH at exit")
     args = p.parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     import dataclasses
 

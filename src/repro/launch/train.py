@@ -3,9 +3,9 @@
     PYTHONPATH=src python -m repro.launch.train --arch olmoe-1b-7b \
         --reduced --steps 20 --mesh 1,1 --ckpt-dir /tmp/ckpt
 
-Full-size configs on the production mesh are exercised through the dry-run
-(this container has one real device); ``--reduced`` runs the same code path
-end-to-end with the smoke-scale config.
+Full-size configs on the production mesh are exercised through the dry-run;
+``--reduced`` runs the same code path end-to-end with the smoke-scale
+config.
 """
 
 from __future__ import annotations
@@ -125,6 +125,8 @@ def main(argv=None) -> dict:
                    help="write a Prometheus text-format metrics snapshot "
                         "(repro.obs.metrics) to PATH at exit")
     args = p.parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")

@@ -21,6 +21,14 @@ def init_model(key: Optional[jax.Array], cfg: ModelConfig,
     return transformer.init_lm(key, cfg, abstract=abstract)
 
 
+def init_placed(key: jax.Array, cfg: ModelConfig, shardings):
+    """Initialise params under jit with ``shardings`` as the output
+    shardings: each device builds only its own shards, so no leaf ever
+    lands whole on one device (call under the bundle's trace context)."""
+    return jax.jit(lambda k: init_model(k, cfg)[0],
+                   out_shardings=shardings)(key)
+
+
 def build_moe_plan(cfg: ModelConfig, tokens_per_dp_shard: int, mesh,
                    store=None, hier_leader_perm=None):
     """One plan-backed EP dispatch plan per (config geometry, mesh).

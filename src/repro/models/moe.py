@@ -57,7 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.configs.base import MoEConfig
 from repro.core import variants as core_variants
 from repro.kernels import ops as kops
@@ -291,13 +291,15 @@ def _route(chunk, router_w, valid, k, n_experts, capacity):
 
     flat_e = idx.reshape(-1)                                  # [T*k]
     flat_valid = jnp.repeat(valid, k)
-    # rank within expert via stable sort
-    sort_ix = jnp.argsort(flat_e, stable=True)
-    sorted_e = flat_e[sort_ix]
+    # rank within expert via stable sort; padding entries sort after every
+    # expert (key n_experts) so they never shift a real entry's position
+    key = jnp.where(flat_valid, flat_e, n_experts)
+    sort_ix = jnp.argsort(key, stable=True)
+    sorted_e = key[sort_ix]
     counts = jax.ops.segment_sum(flat_valid.astype(jnp.int32), flat_e,
                                  num_segments=n_experts)
     starts = jnp.concatenate([jnp.zeros(1, jnp.int32),
-                              jnp.cumsum(counts)[:-1].astype(jnp.int32)])
+                              jnp.cumsum(counts).astype(jnp.int32)])
     pos_sorted = jnp.arange(t * k, dtype=jnp.int32) - starts[sorted_e]
     pos = jnp.zeros(t * k, jnp.int32).at[sort_ix].set(pos_sorted)
     keep = (pos < capacity) & flat_valid
@@ -529,14 +531,8 @@ def _gspmd_dispatch(x2d, nvalid, params, moe: MoEConfig, plan: MoEDispatchPlan):
     buckets = _scatter_buckets(x2d, slot, keep, moe.top_k, e * cap_total, d)
     buckets = cs(buckets.reshape(e, cap_total, d), "experts", None, "embed")
     h = _expert_ffn(buckets, params["w_gate"], params["w_up"], params["w_down"])
-    # Combine gathers back out of h with *token*-sharded indices.  h must be
-    # replicated (cs with no sharded axes) before that gather: jax 0.4.x
-    # GSPMD miscompiles a gather whose operand dim 0 is model-sharded while
-    # the indices are data-sharded — the partial-gather reduction is also
-    # applied over the data axis, returning data_axis_size x the true values
-    # (the "dp-doubled gspmd output" defect from the ROADMAP; minimal repro
-    # in repro.testing.dist_cases.gspmd_gather_miscompile_guard).
-    h = cs(h.reshape(e * cap_total, d), None, None)
+    # Combine gathers back out of h with *token*-sharded indices.
+    h = h.reshape(e * cap_total, d)
     padded = jnp.concatenate([h, jnp.zeros((8, d), h.dtype)], axis=0)
     out = padded[slot] * (keep.astype(h.dtype) * w.astype(h.dtype))[:, None]
     y = out.reshape(t, moe.top_k, d).sum(axis=1)

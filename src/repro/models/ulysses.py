@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import allgatherv_init
 from repro.core import variants as core_variants
 from repro.parallel.sharding import current_mesh, resolve

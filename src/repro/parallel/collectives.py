@@ -20,7 +20,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import axis_size
 
 
 def plan_rs_ag_pair(rows: int, feature_shape, dtype, inner_axis: str, mesh):
@@ -56,8 +55,8 @@ def hierarchical_psum_mean(x: jax.Array, inner_axis: str, outer_axis: str,
     ``x.shape[scatter_dim]`` divisible by the inner axis size and falls
     back to a flat psum otherwise.
     """
-    inner = axis_size(inner_axis)
-    outer = axis_size(outer_axis)
+    inner = jax.lax.axis_size(inner_axis)
+    outer = jax.lax.axis_size(outer_axis)
     n = inner * outer
     if mesh is not None and inner > 1:
         xt = jnp.moveaxis(x, scatter_dim, 0)
@@ -90,5 +89,5 @@ def hierarchical_psum_mean(x: jax.Array, inner_axis: str, outer_axis: str,
 def flat_psum_mean(x: jax.Array, axes) -> jax.Array:
     n = 1
     for a in (axes if isinstance(axes, (tuple, list)) else (axes,)):
-        n *= axis_size(a)
+        n *= jax.lax.axis_size(a)
     return jax.lax.psum(x, axes) / n

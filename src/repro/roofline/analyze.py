@@ -2,7 +2,9 @@
 
     compute term    = HLO_FLOPs / peak_FLOP/s            (per chip)
     memory term     = HLO_bytes / HBM_bw                 (per chip)
-    collective term = wire_bytes / ICI_link_bw           (per chip)
+    collective term = wire_bytes / ICI_bw                (per chip)
+
+The peaks come from ``hw.peaks(device_kind)``: the chip the program targets.
 
 cost_analysis() and the optimized HLO are per-device under SPMD, so the
 terms come out per chip directly (equivalent to the global/chips form).
@@ -100,15 +102,17 @@ class Roofline:
 
 def analyze(cfg: ModelConfig, shape: ShapeConfig, mesh_name: str, chips: int,
             cost: dict, collective_stats: CollectiveStats,
+            *, device_kind: str,
             peak_memory: Optional[float] = None,
             n_micro: int = 1) -> Roofline:
     flops = float(cost.get("flops", 0.0))
     bytes_acc = float(cost.get("bytes accessed", 0.0))
     wire = float(collective_stats.total_wire_bytes)
 
-    compute_s = flops / hw.PEAK_FLOPS_BF16
-    memory_s = bytes_acc / hw.HBM_BW
-    coll_s = wire / hw.ICI_LINK_BW
+    peak = hw.peaks(device_kind)
+    compute_s = flops / peak.flops_bf16
+    memory_s = bytes_acc / peak.hbm_bw
+    coll_s = wire / peak.ici_bw
     dominant = max(
         (("compute", compute_s), ("memory", memory_s), ("collective", coll_s)),
         key=lambda kv: kv[1])[0]

@@ -71,10 +71,12 @@ class ServeEngine:
         # the store was configured, so its INIT saw the warm tier.
         self.moe_plan = self.decode_bundle.meta.get("moe_plan")
         with self.decode_bundle.trace_context():
+            shardings = self.decode_bundle.meta["param_shardings"]
             if params is None:
-                params, _ = model_api.init_model(jax.random.key(seed), cfg)
-            self.params = put_tree(
-                params, self.decode_bundle.meta["param_shardings"])
+                self.params = model_api.init_placed(
+                    jax.random.key(seed), cfg, shardings)
+            else:
+                self.params = put_tree(params, shardings)
 
     def generate(self, prompts: np.ndarray, n_tokens: int,
                  frames: Optional[np.ndarray] = None):

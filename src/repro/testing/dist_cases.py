@@ -17,11 +17,18 @@ for i, a in enumerate(sys.argv):
         _n = int(sys.argv[i + 1])
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + f" --xla_force_host_platform_device_count={_n}")
+# The TPU interpreter runs a kernel as host callbacks, one blocked thread per
+# device, and a callback's operands are read through the CPU runtime's thread
+# pool.  With no more pool threads than devices, a device waiting on a peer's
+# semaphore can starve the peer of the thread it needs to start the kernel
+# (the fused hierarchy kernel on a (2, 4) grid hangs at 8 threads and passes
+# at 9), so give the pool spare threads.
+os.environ.setdefault("PJRT_NPROC", str(max(os.cpu_count() or 1, 2 * _n)))
 
 import jax                    # noqa: E402
 import jax.numpy as jnp       # noqa: E402
 import numpy as np            # noqa: E402
-from repro.compat import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
 CASES = {}
@@ -153,13 +160,8 @@ def plan_and_window_reuse():
 @case
 def ragged_backend_lowers():
     """ragged_all_to_all traces + lowers (XLA:CPU cannot execute it)."""
-    from repro import compat
     from repro.core import AlltoallvPlan, AlltoallvSpec
     from repro.launch.mesh import make_host_mesh
-
-    if not compat.HAS_RAGGED_ALL_TO_ALL:
-        print("SKIPPED: jax.lax.ragged_all_to_all unavailable in this jax")
-        return
 
     p = len(jax.devices())
     mesh = make_host_mesh(p)
@@ -180,13 +182,8 @@ def ragged_backend_lowers():
 @case
 def rma_kernels():
     """Pallas remote-DMA fence/lock kernels vs oracle (TPU interpret mode)."""
-    from repro import compat
     from repro.kernels import ops, ref
     from repro.launch.mesh import make_host_mesh
-
-    if not compat.has_tpu_interpret():
-        print("SKIPPED: no TPU-semantics Pallas interpreter in this jax")
-        return
 
     p = len(jax.devices())
     mesh = make_host_mesh(p)
@@ -924,8 +921,8 @@ def hierarchy_local_elision():
 
 @case
 def fused_pack_fence():
-    """pack_impl='fused' (fused gather+put kernel, or its reference fallback
-    on jax without the TPU interpreter) matches the oracle."""
+    """pack_impl='fused' (fused gather+put kernel, TPU interpret mode here)
+    matches the oracle."""
     from repro.core import alltoallv_init
     from repro.launch.mesh import make_host_mesh
 
@@ -1021,18 +1018,25 @@ def hier_combined_parity():
             if name == "dense":
                 assert plan_h.cross_group_puts == p_outer * (p_outer - 1)
 
-    # fused leader stage (Pallas kernel, or its ppermute fallback here)
-    mesh = make_mesh((2, p // 2), ("o", "i"))
-    send_rows = max(md.round_up(md.max_total_send(dense), 8), 8)
-    recv_rows = max(md.round_up(md.max_total_recv(dense), 8), 8)
-    bufs = reference.make_testbufs(dense, (4,), np.float32, send_rows)
-    expect = reference.alltoallv_global(bufs, dense, recv_rows)
-    x = jax.device_put(jnp.asarray(bufs.reshape(p * send_rows, 4)),
-                       NamedSharding(mesh, P(("o", "i"))))
-    plan_fh = alltoallv_init(dense, (4,), jnp.float32, mesh, axis=("o", "i"),
-                             variant="fence_hierarchy", pack_impl="fused")
-    got = np.asarray(plan_fh.wait(plan_fh.start(x))).reshape(p, recv_rows, 4)
-    _check(got, expect, md.recv_counts(dense), p)
+    # Fused leader stage (Pallas kernel, TPU interpret mode) on the (2, P/2)
+    # grid of all devices and on a (2, 2) grid of the first four (the
+    # geometry of a four-chip host).
+    from jax.sharding import Mesh
+    for pf, grid in dict.fromkeys([(p, (2, p // 2)), (4, (2, 2))]):
+        mesh = Mesh(np.array(jax.devices()[:pf]).reshape(grid), ("o", "i"))
+        sub = dense[:pf, :pf]
+        send_rows = max(md.round_up(md.max_total_send(sub), 8), 8)
+        recv_rows = max(md.round_up(md.max_total_recv(sub), 8), 8)
+        bufs = reference.make_testbufs(sub, (4,), np.float32, send_rows)
+        expect = reference.alltoallv_global(bufs, sub, recv_rows)
+        x = jax.device_put(jnp.asarray(bufs.reshape(pf * send_rows, 4)),
+                           NamedSharding(mesh, P(("o", "i"))))
+        plan_fh = alltoallv_init(sub, (4,), jnp.float32, mesh,
+                                 axis=("o", "i"), variant="fence_hierarchy",
+                                 pack_impl="fused")
+        got = np.asarray(plan_fh.wait(plan_fh.start(x))).reshape(
+            pf, recv_rows, 4)
+        _check(got, expect, md.recv_counts(sub), pf)
 
 
 @case
@@ -1053,9 +1057,9 @@ def auto_variant_dispatch():
                        NamedSharding(mesh, P("x")))
     plan = alltoallv_init(counts, (4,), jnp.float32, mesh, axis="x",
                           variant="auto", cache=cache, autotune_iters=6)
-    from repro import compat
+    from repro.core.autotune import ragged_alltoall_executes
     flat_cands = {"fence", "lock"} | (
-        {"ragged"} if compat.ragged_alltoall_executes() else set())
+        {"ragged"} if ragged_alltoall_executes() else set())
     assert set(plan.auto_choice["times"]) == flat_cands
     assert plan.spec.variant == plan.auto_choice["variant"]
     got = np.asarray(plan.wait(plan.start(x))).reshape(p, recv_rows, 4)
@@ -1081,9 +1085,8 @@ def auto_variant_dispatch():
 @case
 def auto_ragged_candidate():
     """ragged joins the variant="auto" candidate set exactly when
-    lax.ragged_all_to_all exists AND the backend can execute it: excluded
-    (and never measured) on CPU / old jax, included when the gate passes."""
-    from repro import compat
+    the backend can execute lax.ragged_all_to_all: excluded (and never
+    measured) on CPU, included when the gate passes."""
     from repro.core import AlltoallvSpec, PlanCache, alltoallv_init, autotune
     from repro.launch.mesh import make_host_mesh
 
@@ -1094,7 +1097,7 @@ def auto_ragged_candidate():
                          dtype=jnp.float32, axis=("x",))
 
     cands = autotune.candidate_variants(spec, mesh)
-    assert ("ragged" in cands) == compat.ragged_alltoall_executes()
+    assert ("ragged" in cands) == autotune.ragged_alltoall_executes()
 
     # End-to-end: auto measures exactly the candidate set for this host —
     # on a CPU container that means ragged was *not* measured.
@@ -1109,8 +1112,8 @@ def auto_ragged_candidate():
     # Force the gate: with executability faked, the candidate fold-in logic
     # includes ragged on a single axis and keeps it off grouped specs (the
     # ragged spec takes one mesh axis).
-    orig = compat.ragged_alltoall_executes
-    compat.ragged_alltoall_executes = lambda: True
+    orig = autotune.ragged_alltoall_executes
+    autotune.ragged_alltoall_executes = lambda: True
     try:
         assert "ragged" in autotune.candidate_variants(spec, mesh)
         if p % 2 == 0:
@@ -1120,7 +1123,7 @@ def auto_ragged_candidate():
                                   dtype=jnp.float32, axis=("o", "i"))
             assert "ragged" not in autotune.candidate_variants(spec2, mesh2)
     finally:
-        compat.ragged_alltoall_executes = orig
+        autotune.ragged_alltoall_executes = orig
 
 
 @case
@@ -1255,15 +1258,16 @@ def planstore_fleet_prewarm():
 
 @case
 def gspmd_gather_miscompile_guard():
-    """Regression for the ROADMAP "gspmd = data_axis_size x a2a" defect.
+    """Regression for the "gspmd = data_axis_size x a2a" defect.
 
-    Root cause (not in this repo): jax 0.4.x GSPMD miscompiles a gather
-    whose operand dim 0 is model-sharded while the indices are data-sharded
-    — the partial-gather reduction is applied over the data axis as well,
-    multiplying every element by data_axis_size.  The minimal pattern is
-    reproduced below; the MoE gspmd path guards it by replicating expert
-    outputs before the combine gather, which this case pins down by
-    asserting mesh invariance of the full layer."""
+    An older jax's GSPMD miscompiled a gather whose operand dim 0 is
+    model-sharded while the indices are data-sharded — the partial-gather
+    reduction was applied over the data axis as well, multiplying every
+    element by data_axis_size — and the MoE gspmd path replicated expert
+    outputs before the combine gather to dodge it.  The installed jax
+    partitions that gather correctly, so the guard is gone: this case pins
+    the minimal pattern to the right values and asserts mesh invariance of
+    the unguarded layer."""
     import dataclasses
 
     from repro.configs.base import MoEConfig
@@ -1271,9 +1275,9 @@ def gspmd_gather_miscompile_guard():
     from repro.models import moe as moe_mod
     from repro.parallel.sharding import DEFAULT_RULES, ParamFactory, axis_rules
 
-    # --- minimal repro of the upstream defect (documentation, not a test
-    # of this repo): gather from a model-sharded operand with data-sharded
-    # indices, feeding a weighted per-token combine (the MoE combine shape).
+    # --- the once-miscompiled pattern: gather from a model-sharded operand
+    # with data-sharded indices, feeding a weighted per-token combine (the
+    # MoE combine shape).
     mesh = make_mesh((2, 4), ("data", "model"))
     t, k, d = 256, 2, 64
     rng = np.random.default_rng(1)
@@ -1294,16 +1298,9 @@ def gspmd_gather_miscompile_guard():
         jax.device_put(jnp.asarray(wgt), NamedSharding(mesh, P("data")))))
     padded = np.concatenate([h, np.zeros((8, d), np.float32)])
     want = (padded[idx] * wgt[:, None]).reshape(t, k, d).sum(axis=1)
-    if np.allclose(got, want, atol=1e-5):
-        print("NOTE: upstream gather partitioner defect no longer "
-              "reproduces in this jax; the moe guard is now belt-and-braces")
-    else:
-        ratio = got[np.abs(want) > 1e-3] / want[np.abs(want) > 1e-3]
-        np.testing.assert_allclose(ratio, 2.0, rtol=1e-4,
-                                   err_msg="defect shape changed: expected "
-                                           "exactly data_axis_size x values")
+    np.testing.assert_allclose(got, want, atol=1e-5)
 
-    # --- the guarded MoE layer must be mesh-invariant -------------------
+    # --- the gspmd MoE layer must be mesh-invariant ---------------------
     d_model, tokens = 64, 256
     base = MoEConfig(n_experts=8, top_k=2, d_expert=32, capacity_factor=8.0,
                      dispatch="gspmd")
@@ -1379,8 +1376,8 @@ def moe_hier_dispatch():
         np.testing.assert_allclose(outs["hier"], outs["flat"],
                                    rtol=2e-4, atol=2e-5)
 
-        # Fused leader stage inside the embedded plan (Pallas kernel on TPU,
-        # its jnp ppermute reference here) is bit-identical to the jnp path.
+        # Fused leader stage inside the embedded plan (Pallas kernel, TPU
+        # interpret mode here) is bit-identical to the jnp path.
         mcfg = dataclasses.replace(base, dispatch="persistent_a2a",
                                    a2a_variant="fence_hierarchy")
         plan_f = moe_mod.MoEDispatchPlan.build(

@@ -38,7 +38,7 @@ def compressed_sync(mesh, specs, dp_axes):
     axis left the psum degenerates to the identity and the sync is a pure
     local quantize+EF pass, so the state threading is identical either way.
     """
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.parallel import compression
 
     dp = tuple(a for a in dp_axes
@@ -80,7 +80,7 @@ def persistent_rs_sync(mesh, specs, dp_axes, error_feedback: bool = False):
     """
     import numpy as np
 
-    from repro.compat import shard_map
+    from jax import shard_map
     from repro.core import allgatherv_init, metadata as md, reduce_scatter_init
     from repro.parallel import compression
 

@@ -104,9 +104,9 @@ class Trainer:
     # -- state management ----------------------------------------------------
     def init_state(self) -> None:
         with self.bundle.trace_context():
-            self.params, _ = model_api.init_model(
-                jax.random.key(self.tcfg.seed), self.cfg)
-            self.params = put_tree(self.params, self.bundle.meta["param_shardings"])
+            self.params = model_api.init_placed(
+                jax.random.key(self.tcfg.seed), self.cfg,
+                self.bundle.meta["param_shardings"])
             self.opt_state = opt_mod.init_opt_state(
                 self.params, self.bundle.meta["adamw"],
                 grad_err=self.bundle.meta.get("grad_compression", False))

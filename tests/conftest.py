@@ -28,7 +28,11 @@ SRC = os.path.join(ROOT, "src")
 
 
 def run_case(case: str, devices: int = 8, timeout: int = 900) -> str:
-    """Run one repro.testing.dist_cases case in a subprocess."""
+    """Run one repro.testing.dist_cases case in a subprocess.
+
+    CPU only: the child runs on fake host devices.  Never point it at a
+    TPU — this process has imported JAX, and a parent that holds the chip
+    leaves a child that needs it failing or hanging."""
     env = dict(os.environ, PYTHONPATH=SRC + os.pathsep
                + os.environ.get("PYTHONPATH", ""))
     r = subprocess.run(

@@ -19,6 +19,7 @@ from repro.kernels import ops, ref
     (64, 256, jnp.bfloat16),
     (8, 64, jnp.float32),
     (128, 512, jnp.float16),
+    (48, 200, jnp.int8),         # four lanes per 32-bit word
 ])
 def test_gather_rows_sweep(rows, feat, dtype):
     rng = np.random.default_rng(rows + feat)
@@ -68,3 +69,25 @@ def test_a2a_oracle_is_involution():
     once = ref.a2a_bucketed_ref(x, p, cap)
     twice = ref.a2a_bucketed_ref(once, p, cap)
     np.testing.assert_array_equal(twice, x)
+
+
+@pytest.mark.parametrize("dtype,rows,d,f,n", [
+    (jnp.float32, 24, 128, 128, 16),
+    (jnp.bfloat16, 40, 200, 96, 12),     # D, F and N all need padding
+])
+def test_fused_unpack_matmul_kernel_interpret(dtype, rows, d, f, n):
+    """The Pallas gather-matmul (HLO interpreter) equals its jnp form."""
+    rng = np.random.default_rng(rows + d)
+    e = 3
+    x = jnp.asarray(rng.standard_normal((rows, d)), dtype)
+    w = jnp.asarray(rng.standard_normal((e, d, f)) / np.sqrt(d), dtype)
+    idx = jnp.asarray(rng.integers(0, rows, (e, n)), jnp.int32)
+    valid = jnp.asarray(rng.integers(0, 2, (e, n)), jnp.int32)
+    got = ops.fused_unpack_matmul(x, idx, w, valid=valid, interpret=True)
+    h = ref.gather_rows_ref(x, idx.reshape(-1), valid.reshape(-1))
+    want = jnp.einsum("end,edf->enf", h.reshape(e, n, d).astype(jnp.float32),
+                      w.astype(jnp.float32))
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), rtol=tol,
+                               atol=tol)

@@ -12,7 +12,7 @@ import numpy as np
 import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 from repro.configs.base import MoEConfig
 from repro.core import alltoallv_init, metadata as md
 from repro.launch.mesh import make_host_mesh, make_mesh

@@ -53,6 +53,26 @@ def test_route_invariants(t, e, k, seed):
         assert float(lb) > 0.5
 
 
+@pytest.mark.parametrize("n_valid", [1, 3, 5])
+def test_route_padding_does_not_shift_valid_slots(n_valid):
+    """Padding tokens (a decode chunk padded to the 8-row tile) must not
+    push real entries past capacity: routing the valid prefix of a padded
+    chunk keeps exactly what routing the unpadded tokens keeps."""
+    t, e, k, cap = 8, 8, 2, 2
+    rng = np.random.default_rng(n_valid)
+    chunk = jnp.asarray(rng.standard_normal((t, 16)), jnp.float32)
+    router = jnp.asarray(rng.standard_normal((16, e)), jnp.float32)
+    valid = jnp.arange(t) < n_valid
+    slot, keep, w, _, _ = moe_mod._route(chunk, router, valid, k, e, cap)
+    slot0, keep0, w0, _, _ = moe_mod._route(
+        chunk[:n_valid], router, jnp.ones(n_valid, bool), k, e, cap)
+    np.testing.assert_array_equal(np.asarray(keep)[:n_valid * k],
+                                  np.asarray(keep0))
+    np.testing.assert_array_equal(np.asarray(slot)[:n_valid * k],
+                                  np.asarray(slot0))
+    assert not np.asarray(keep)[n_valid * k:].any()
+
+
 def test_dispatch_impls_agree_single_device():
     base = MoEConfig(n_experts=8, top_k=2, d_expert=32, capacity_factor=8.0)
     rng = np.random.default_rng(0)
