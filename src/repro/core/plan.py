@@ -70,6 +70,18 @@ from .window import Window, WindowCache
 
 VARIANTS = ("fence", "lock", "fence_hierarchy", "ragged")
 
+# Named scopes of an epoch's stages: HLO metadata and profiler traces name
+# each device op of an alltoallv epoch (standalone or embedded) by them.
+A2A_PACK, A2A_EXCHANGE, A2A_UNPACK = "a2a/pack", "a2a/exchange", "a2a/unpack"
+
+
+def _scoped(name: str, fn: Callable) -> Callable:
+    """``fn`` traced under ``jax.named_scope(name)``."""
+    def call(*args, **kwargs):
+        with jax.named_scope(name):
+            return fn(*args, **kwargs)
+    return call
+
 
 class WarmStartError(Exception):
     """A store artifact does not fit the plan being built (shape or schedule
@@ -383,8 +395,8 @@ class ExchangePlan:
             INIT_STATS.bump("warm_inits")
         else:
             INIT_STATS.bump("cold_inits")
-        # Prebuilt once so the epoch hot path emits spans with zero dict
-        # allocation (``TRACER.emit_span`` stores the same dict by ref).
+        # Ring args of this plan's ``plan.start`` and recorded ``epoch``
+        # spans, built once.
         self._digest = self.signature.digest
         self._epoch_span_args = {"digest": self._digest,
                                  "variant": spec.variant,
@@ -453,7 +465,12 @@ class ExchangePlan:
             pack, unpack = kops.pack, kops.unpack
         else:
             kops = None
-            pack, unpack = variants.pack_rows, partial(variants.unpack_rows)
+            pack, unpack = variants.pack_rows, variants.unpack_rows
+        pack, unpack = _scoped(A2A_PACK, pack), _scoped(A2A_UNPACK, unpack)
+        pack_rows = _scoped(A2A_PACK, variants.pack_rows)
+        unpack_rows = _scoped(A2A_UNPACK, variants.unpack_rows)
+        hier_exchange = _scoped(A2A_EXCHANGE,
+                                variants.hierarchy_exchange_combined)
         # Non-identity codec: the heavy gather/exchange below runs at wire
         # width (encode fused into the pack path); per-row fp32 scales ride
         # the same variant exchange as a tiny [rows, 1] side channel (every
@@ -470,10 +487,11 @@ class ExchangePlan:
             in-graph recomputation below runs every epoch instead."""
             i = self._axis_index()
             if spec.variant == "ragged":
-                return variants.ragged_exchange(
-                    x, window,
-                    self._sd_tbl[i], self._sc_tbl[i],
-                    self._put_tbl[i], self._rc_tbl[i], a2a_axis)
+                with jax.named_scope(A2A_EXCHANGE):
+                    return variants.ragged_exchange(
+                        x, window,
+                        self._sd_tbl[i], self._sc_tbl[i],
+                        self._put_tbl[i], self._rc_tbl[i], a2a_axis)
 
             scales = None
             if codec is not None:
@@ -499,12 +517,12 @@ class ExchangePlan:
                         mesh_axes=tuple(self.mesh.axis_names))
                 else:
                     stage2 = None
-                buckets = variants.hierarchy_exchange_combined(
+                buckets = hier_exchange(
                     x, rows[:6], self.hier_schedule,
                     spec.axis[0], spec.axis[1], stage2_impl=stage2)
                 rsrc, rvalid = rows[6], rows[7]
                 if scales is not None:
-                    sc_buckets = variants.hierarchy_exchange_combined(
+                    sc_buckets = hier_exchange(
                         scales, rows[:6], self.hier_schedule,
                         spec.axis[0], spec.axis[1], stage2_impl=None)
             else:
@@ -517,31 +535,32 @@ class ExchangePlan:
                         self._rc_tbl[i], self._rd_tbl[i], p, cap, self.recv_rows)
 
                 def exchange(packed):
-                    if spec.variant == "fence":
-                        return variants.fence_exchange(packed, a2a_axis)
-                    return variants.lock_exchange(
-                        packed, a2a_axis, p, cap,
-                        self.round_capacities, spec.lock_schedule)
+                    with jax.named_scope(A2A_EXCHANGE):
+                        if spec.variant == "fence":
+                            return variants.fence_exchange(packed, a2a_axis)
+                        return variants.lock_exchange(
+                            packed, a2a_axis, p, cap,
+                            self.round_capacities, spec.lock_schedule)
 
                 if spec.pack_impl == "fused":
                     # Pack fused into the remote-DMA kernel: rows are gathered
                     # straight into the put source tile, never materializing the
                     # padded [P*C, F] intermediate in HBM.
-                    buckets = kops.fused_pack_alltoallv(
-                        x, src, valid, p=p, capacity=cap, axis=a2a_axis,
-                        mesh_axes=tuple(self.mesh.axis_names))
+                    with jax.named_scope(A2A_EXCHANGE):
+                        buckets = kops.fused_pack_alltoallv(
+                            x, src, valid, p=p, capacity=cap, axis=a2a_axis,
+                            mesh_axes=tuple(self.mesh.axis_names))
                 else:
                     buckets = exchange(pack(x, src, valid))
                 if scales is not None:
-                    sc_buckets = exchange(
-                        variants.pack_rows(scales, src, valid))
+                    sc_buckets = exchange(pack_rows(scales, src, valid))
 
             out = unpack(buckets, rsrc, rvalid)
             if codec is not None:
                 if k:
                     out, sc_out = wirecodec.split_rows(out, k)
                 else:
-                    sc_out = (variants.unpack_rows(sc_buckets, rsrc, rvalid)
+                    sc_out = (unpack_rows(sc_buckets, rsrc, rvalid)
                               if scales is not None else None)
                 out = codec.decode(out, sc_out, out_dtype)
             # Write-through into the window: padding keeps stale window bytes
@@ -600,6 +619,10 @@ class ExchangePlan:
         a2a_axis = spec.axis[0] if len(spec.axis) == 1 else tuple(spec.axis)
         codec = wirecodec.get(spec.codec) if spec.codec != "identity" else None
         out_dtype = jnp.dtype(spec.dtype)
+        pack_rows = _scoped(A2A_PACK, variants.pack_rows)
+        unpack_rows = _scoped(A2A_UNPACK, variants.unpack_rows)
+        hier_exchange = _scoped(A2A_EXCHANGE,
+                                variants.hierarchy_exchange_combined)
 
         if spec.variant == "fence_hierarchy":
             tbls = tuple(jnp.asarray(t) for t in self._table_host)
@@ -621,18 +644,17 @@ class ExchangePlan:
                     x_wire, scales = codec.encode(x)
                 else:
                     x_wire = x
-                buckets = variants.hierarchy_exchange_combined(
+                buckets = hier_exchange(
                     x_wire, rows[:6], sched, spec.axis[0], spec.axis[1],
                     stage2_impl=stage2)
-                out = variants.unpack_rows(buckets, rows[6], rows[7])
+                out = unpack_rows(buckets, rows[6], rows[7])
                 if codec is not None:
                     sc_out = None
                     if scales is not None:
-                        sc_buckets = variants.hierarchy_exchange_combined(
+                        sc_buckets = hier_exchange(
                             scales, rows[:6], sched, spec.axis[0],
                             spec.axis[1], stage2_impl=None)
-                        sc_out = variants.unpack_rows(
-                            sc_buckets, rows[6], rows[7])
+                        sc_out = unpack_rows(sc_buckets, rows[6], rows[7])
                     out = codec.decode(out, sc_out, out_dtype)
                 return out
         elif self.identity_maps:
@@ -641,11 +663,12 @@ class ExchangePlan:
             # pack_impl is moot — the epoch IS the bare exchange (plus the
             # wire encode/decode and its scale side channel under a codec).
             def bare_exchange(payload):
-                if spec.variant == "fence":
-                    return variants.fence_exchange(payload, a2a_axis)
-                return variants.lock_exchange(
-                    payload, a2a_axis, p, cap,
-                    self.round_capacities, spec.lock_schedule)
+                with jax.named_scope(A2A_EXCHANGE):
+                    if spec.variant == "fence":
+                        return variants.fence_exchange(payload, a2a_axis)
+                    return variants.lock_exchange(
+                        payload, a2a_axis, p, cap,
+                        self.round_capacities, spec.lock_schedule)
 
             def embedded(x: jax.Array) -> jax.Array:
                 if codec is None:
@@ -675,6 +698,8 @@ class ExchangePlan:
             else:
                 kops = None
                 pack_fn, unpack_fn = variants.pack_rows, variants.unpack_rows
+            pack_fn = _scoped(A2A_PACK, pack_fn)
+            unpack_fn = _scoped(A2A_UNPACK, unpack_fn)
 
             def embedded(x: jax.Array) -> jax.Array:
                 i = self._axis_index()
@@ -690,17 +715,19 @@ class ExchangePlan:
                     x, scales = wirecodec.inline_rows(x, scales, k), None
 
                 def exchange(packed):
-                    if spec.variant == "fence":
-                        return variants.fence_exchange(packed, a2a_axis)
-                    return variants.lock_exchange(
-                        packed, a2a_axis, p, cap,
-                        self.round_capacities, spec.lock_schedule)
+                    with jax.named_scope(A2A_EXCHANGE):
+                        if spec.variant == "fence":
+                            return variants.fence_exchange(packed, a2a_axis)
+                        return variants.lock_exchange(
+                            packed, a2a_axis, p, cap,
+                            self.round_capacities, spec.lock_schedule)
 
                 if spec.pack_impl == "fused" and spec.variant == "fence":
-                    buckets = kops.fused_pack_alltoallv(
-                        x, tbls[0][i], tbls[1][i], p=p, capacity=cap,
-                        axis=a2a_axis,
-                        mesh_axes=tuple(self.mesh.axis_names))
+                    with jax.named_scope(A2A_EXCHANGE):
+                        buckets = kops.fused_pack_alltoallv(
+                            x, tbls[0][i], tbls[1][i], p=p, capacity=cap,
+                            axis=a2a_axis,
+                            mesh_axes=tuple(self.mesh.axis_names))
                 else:
                     buckets = exchange(pack_fn(x, tbls[0][i], tbls[1][i]))
                 out = unpack_fn(buckets, tbls[2][i], tbls[3][i])
@@ -709,9 +736,9 @@ class ExchangePlan:
                     if k:
                         out, sc_out = wirecodec.split_rows(out, k)
                     elif scales is not None:
-                        sc_buckets = exchange(variants.pack_rows(
+                        sc_buckets = exchange(pack_rows(
                             scales, tbls[0][i], tbls[1][i]))
-                        sc_out = variants.unpack_rows(
+                        sc_out = unpack_rows(
                             sc_buckets, tbls[2][i], tbls[3][i])
                     out = codec.decode(out, sc_out, out_dtype)
                 return out
@@ -749,17 +776,18 @@ class ExchangePlan:
 
     # -- START / WAIT / FREE ----------------------------------------------------
     def start(self, sendbuf: jax.Array) -> jax.Array:
-        """Launch one epoch. Returns the (async) recv buffer."""
+        """Launch one epoch. Returns the (async) recv buffer.
+
+        Traced as ``plan.start``: the dispatch, which on an accelerator
+        returns before the epoch ends (``plan.wait`` is the block)."""
         self.compile()
         win = self.window.materialize(self.global_recv_shape, self._x_sharding)
-        t0 = time.perf_counter()
-        out = self._compiled(sendbuf, win, *self._table_args)
-        if self.record_starts:
+        with TRACER.span("plan.start", "execute", **self._epoch_span_args):
+            t0 = time.perf_counter()
+            out = self._compiled(sendbuf, win, *self._table_args)
             t1 = time.perf_counter()
+        if self.record_starts:
             EXEC_TELEMETRY.record(self._digest, t1 - t0)
-            if TRACER.enabled:
-                TRACER.emit_span("epoch", "execute", t0, t1,
-                                 self._epoch_span_args)
         self.window.adopt(out)   # donated-in, aliased-out: window reuse
         self.starts += 1
         return out
@@ -781,21 +809,20 @@ class ExchangePlan:
         slot = self.starts % depth
         win = self.window.materialize(
             self.global_recv_shape, self._x_sharding, slot=slot)
-        t0 = time.perf_counter()
-        out = self._compiled(sendbuf, win, *self._table_args)
-        if self.record_starts:
+        with TRACER.span("plan.start", "execute", **self._epoch_span_args):
+            t0 = time.perf_counter()
+            out = self._compiled(sendbuf, win, *self._table_args)
             t1 = time.perf_counter()
+        if self.record_starts:
             EXEC_TELEMETRY.record(self._digest, t1 - t0)
-            if TRACER.enabled:
-                TRACER.emit_span("epoch", "execute", t0, t1,
-                                 self._epoch_span_args)
         self.window.adopt(out, slot=slot)
         self.starts += 1
         return out
 
     @staticmethod
     def wait(recvbuf: jax.Array) -> jax.Array:
-        return jax.block_until_ready(recvbuf)
+        with TRACER.span("plan.wait", "execute"):
+            return jax.block_until_ready(recvbuf)
 
     def record_epoch(self, seconds: float, t_end: "float | None" = None) -> None:
         """Record one externally timed epoch into this plan's telemetry

@@ -38,7 +38,7 @@ def main(argv=None):
     p.add_argument("--trace", default=None, metavar="PATH",
                    help="enable span tracing (repro.obs) and export a "
                         "Chrome-trace JSON to PATH at exit — INIT spans plus "
-                        "prefill/decode EXECUTE spans")
+                        "the serve.* EXECUTE spans of each generate call")
     p.add_argument("--metrics-port", type=int, default=None,
                    help="serve Prometheus metrics on 127.0.0.1:PORT for the "
                         "lifetime of the process (repro.obs.MetricsServer); "

@@ -242,7 +242,13 @@ def make_decode_bundle(
     *,
     rules: Optional[dict] = None,
 ) -> StepBundle:
-    """One new token against a KV cache / recurrent state of shape.seq_len."""
+    """One new token against a KV cache / recurrent state of shape.seq_len.
+
+    ``decode_step(params, caches, tokens, index, experts_touched)`` returns
+    ``(next tokens, caches, experts_touched + this step's count)``: the
+    count (experts with a kept assignment, summed over MoE layers; 0
+    without MoE) stays on the device, so a caller sums a whole generation
+    with no host sync per token."""
     if rules is None:
         rules = LONG_CONTEXT_RULES if shape.name == "long_500k" else DECODE_RULES
     rules = dict(rules)
@@ -262,39 +268,41 @@ def make_decode_bundle(
             cache_logical = whisper.dec_cache_logical_specs(cfg)
             cache_sh = specs_to_shardings(cache_logical, mesh, caches_abs)
 
-            def decode_step(params, caches, tokens, index):
+            def decode_step(params, caches, tokens, index, experts_touched):
                 logits, new_caches = whisper.decode(
                     params, cfg, tokens, None, caches=caches,
                     cache_index=index, remat=False)
                 nxt = jnp.argmax(logits[:, -1].astype(jnp.float32), -1)
-                return nxt.astype(jnp.int32)[:, None], new_caches
+                return nxt.astype(jnp.int32)[:, None], new_caches, experts_touched
         else:
             caches_abs = transformer.cache_shape_specs(cfg, b, shape.seq_len,
                                                        cache_dtype)
             cache_logical = transformer.cache_logical_specs(cfg)
             cache_sh = specs_to_shardings(cache_logical, mesh, caches_abs)
 
-            def decode_step(params, caches, tokens, index):
-                logits, _, new_caches = transformer.forward(
+            def decode_step(params, caches, tokens, index, experts_touched):
+                logits, aux, new_caches = transformer.forward(
                     params, cfg, tokens, moe_plan=moe_plan, caches=caches,
                     cache_index=index, remat=False)
                 nxt = jnp.argmax(logits[:, -1].astype(jnp.float32), -1)
-                return nxt.astype(jnp.int32)[:, None], new_caches
+                return (nxt.astype(jnp.int32)[:, None], new_caches,
+                        experts_touched + aux[2])
 
         tok_sh = NamedSharding(mesh, resolve(("batch", None)))
         jitted = jax.jit(
             decode_step,
-            in_shardings=(param_sh, cache_sh, tok_sh, _rep(mesh)),
-            out_shardings=(tok_sh, cache_sh),
+            in_shardings=(param_sh, cache_sh, tok_sh, _rep(mesh), _rep(mesh)),
+            out_shardings=(tok_sh, cache_sh, _rep(mesh)),
             donate_argnums=(1,),
         )
 
     tok_abs = jax.ShapeDtypeStruct((b, 1), jnp.int32)
     idx_abs = jax.ShapeDtypeStruct((), jnp.int32)
+    touched_abs = jax.ShapeDtypeStruct((), jnp.float32)
     return StepBundle(
         name=f"decode:{cfg.name}:{shape.name}",
         mesh=mesh, rules=rules, jitted=jitted,
-        arg_specs=(params_abs, caches_abs, tok_abs, idx_abs),
+        arg_specs=(params_abs, caches_abs, tok_abs, idx_abs, touched_abs),
         meta={"cfg": cfg, "shape": shape, "moe_plan": moe_plan,
               "param_shardings": param_sh, "cache_shardings": cache_sh,
               "logical_specs": logical_specs, "cache_dtype": cache_dtype},

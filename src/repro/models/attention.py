@@ -237,10 +237,13 @@ def apply_attention(
         k_pos = jnp.broadcast_to(jnp.arange(k.shape[1])[None], (b, k.shape[1]))
         new_cache = kv_cache
     elif kv_cache is not None:
-        ck = jax.lax.dynamic_update_slice_in_dim(kv_cache["k"], k, cache_index, axis=1)
-        cv = jax.lax.dynamic_update_slice_in_dim(kv_cache["v"], v, cache_index, axis=1)
-        ck = cs(ck, "batch", "seq", "kv_heads", "head_dim")
-        cv = cs(cv, "batch", "seq", "kv_heads", "head_dim")
+        with jax.named_scope("kv_cache"):
+            ck = jax.lax.dynamic_update_slice_in_dim(kv_cache["k"], k,
+                                                     cache_index, axis=1)
+            cv = jax.lax.dynamic_update_slice_in_dim(kv_cache["v"], v,
+                                                     cache_index, axis=1)
+            ck = cs(ck, "batch", "seq", "kv_heads", "head_dim")
+            cv = cs(cv, "batch", "seq", "kv_heads", "head_dim")
         new_cache = {"k": ck, "v": cv}
         k, v = ck, cv
         k_pos = jnp.broadcast_to(jnp.arange(k.shape[1])[None], (b, k.shape[1]))

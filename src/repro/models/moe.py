@@ -42,7 +42,13 @@ published to / warm-started from the plan store (``--plan-store`` /
 plan (the uniform pattern is symmetric).
 
 Routing is Switch/GShard-style top-k with capacity factor, aux load-balance
-loss and router z-loss.
+loss and router z-loss.  The aux vector also carries the number of experts
+with at least one kept assignment (per routing group), which the serving
+engine sums into its experts-touched counter.
+
+Each stage runs under a ``jax.named_scope`` (``moe/router``,
+``moe/dispatch``, ``moe/expert_ffn``, ``moe/combine``), so a profiler trace
+names the device ops of each stage in their ``tf_op`` path.
 """
 
 from __future__ import annotations
@@ -64,6 +70,9 @@ from repro.kernels import ops as kops
 from repro.parallel import wirecodec
 from repro.parallel.sharding import (ScopedFactory, active_rules, batch_ways,
                                      cs, current_mesh, normal_init, resolve)
+
+# apply_moe's aux vector: (load-balance loss, router z-loss, experts touched)
+N_AUX = 3
 
 
 # ---------------------------------------------------------------------------
@@ -280,7 +289,10 @@ class MoEDispatchPlan:
 
 
 def _route(chunk, router_w, valid, k, n_experts, capacity):
-    """Returns (slot [T*k], keep [T*k], weight [T*k], aux (lb, z))."""
+    """Returns (slot [T*k], keep [T*k], weight [T*k], counts [E],
+    aux (lb, z)).  ``counts`` are the valid assignments per expert before
+    the capacity cut; an expert's first assignment always fits (capacity
+    >= 1), so ``counts > 0`` marks the experts with a kept assignment."""
     t = chunk.shape[0]
     logits = (chunk @ router_w).astype(jnp.float32)          # [T, E]
     logits = jnp.where(valid[:, None], logits, -1e9)
@@ -315,6 +327,11 @@ def _route(chunk, router_w, valid, k, n_experts, capacity):
     lse = jnp.where(valid, jax.nn.logsumexp(logits, axis=-1), 0.0)
     z = jnp.sum(jnp.square(lse)) / nvalid
     return slot, keep, w.reshape(-1), counts, (lb, z)
+
+
+def _experts_touched(counts):
+    """Experts with at least one kept assignment, as float32 (an aux entry)."""
+    return (counts > 0).sum().astype(jnp.float32)
 
 
 def _scatter_buckets(chunk, slot, keep, k, n_rows, d):
@@ -392,8 +409,9 @@ def _a2a_shard_body(tokens, router_w, w_gate, w_up, w_down,
     chunk = jax.lax.dynamic_slice_in_dim(tokens, m * t_loc, t_loc, axis=0)
     valid = (m * t_loc + jnp.arange(t_loc)) < t_have
 
-    slot, keep, w, counts, aux = _route(chunk, router_w, valid,
-                                        plan.top_k, plan.n_experts, cap)
+    with jax.named_scope("moe/router"):
+        slot, keep, w, counts, aux = _route(chunk, router_w, valid,
+                                            plan.top_k, plan.n_experts, cap)
 
     # Fused wire codec: token rows are encoded ONCE, before the capacity
     # scatter, so the scatter, both exchanges, and the FFN gather all move
@@ -411,21 +429,24 @@ def _a2a_shard_body(tokens, router_w, w_gate, w_up, w_down,
         wire, sc = codec.encode(rows)
         return wirecodec.inline_rows(wire, sc, lanes) if lanes else wire
 
-    wrows = to_wire(chunk)
-    dw = wrows.shape[1]
-    packed = _scatter_buckets(wrows, slot, keep, plan.top_k,
-                              plan.n_experts * cap, dw)
+    with jax.named_scope("moe/dispatch"):
+        wrows = to_wire(chunk)
+        dw = wrows.shape[1]
+        packed = _scatter_buckets(wrows, slot, keep, plan.top_k,
+                                  plan.n_experts * cap, dw)
 
-    if not persistent and axis:
-        # Non-persistent: re-exchange metadata every call (per-target counts
-        # + in-graph displacement math) — the overhead persistence removes.
-        per_peer = counts.reshape(ep, e_loc).sum(-1).astype(jnp.int32)
-        rcounts = core_variants.exchange_counts_in_graph(per_peer, axis)
-        rdispls = core_variants.displacements_in_graph(rcounts)
-        # Fold the (otherwise unused) metadata into the data path so XLA
-        # cannot DCE it: scale-by-one keyed on the recomputed displacements.
-        one = (rdispls[-1] >= 0).astype(packed.dtype)
-        packed = packed * one
+        if not persistent and axis:
+            # Non-persistent: re-exchange metadata every call (per-target
+            # counts + in-graph displacement math) — the overhead
+            # persistence removes.
+            per_peer = counts.reshape(ep, e_loc).sum(-1).astype(jnp.int32)
+            rcounts = core_variants.exchange_counts_in_graph(per_peer, axis)
+            rdispls = core_variants.displacements_in_graph(rcounts)
+            # Fold the (otherwise unused) metadata into the data path so
+            # XLA cannot DCE it: scale-by-one keyed on the recomputed
+            # displacements.
+            one = (rdispls[-1] >= 0).astype(packed.dtype)
+            packed = packed * one
 
     # alltoallv over the EP axis.  Each per-peer chunk bucket is e_local
     # slots of chunk_capacity rows = plan.chunk_peer_rows rows — the uniform
@@ -436,9 +457,10 @@ def _a2a_shard_body(tokens, router_w, w_gate, w_up, w_down,
     packed4 = packed.reshape(ep, e_loc, cap, dw)
 
     def dispatch_chunk(c):
-        blk = jax.lax.slice_in_dim(packed4, c * ck, (c + 1) * ck, axis=2)
-        blk = blk.reshape(ep * e_loc * ck, dw)
-        return exchange(blk) if exchange is not None else blk
+        with jax.named_scope("moe/dispatch"):
+            blk = jax.lax.slice_in_dim(packed4, c * ck, (c + 1) * ck, axis=2)
+            blk = blk.reshape(ep * e_loc * ck, dw)
+            return exchange(blk) if exchange is not None else blk
 
     # Receive-side regroup table: expert e's FFN rows, in [peer-major,
     # slot-minor] order, addressed directly in the exchanged chunk buffer
@@ -457,20 +479,22 @@ def _a2a_shard_body(tokens, router_w, w_gate, w_up, w_down,
         # then the reverse exchange (all_to_all is an involution on the
         # bucket layout).  Under a codec the receive buffer holds wire
         # rows: the scale lanes split off and dequant rides the gather.
-        if lanes:
-            xq, xsc = wirecodec.split_rows(xch, lanes)
-        else:
-            xq, xsc = xch, None
-        g = kops.fused_unpack_matmul(xq, regroup_idx,
-                                     w_gate.astype(ctype), scales=xsc)
-        u = kops.fused_unpack_matmul(xq, regroup_idx,
-                                     w_up.astype(ctype), scales=xsc)
-        a = jax.nn.silu(g) * u
-        h = jnp.einsum("ecf,efd->ecd", a, w_down.astype(ctype))
-        back = h.reshape(e_loc, ep, ck, d).transpose(1, 0, 2, 3)
-        back = to_wire(back.reshape(ep * e_loc * ck, d).astype(ctype))
-        out = exchange(back) if exchange is not None else back
-        return out.reshape(ep, e_loc, ck, dw)
+        with jax.named_scope("moe/expert_ffn"):
+            if lanes:
+                xq, xsc = wirecodec.split_rows(xch, lanes)
+            else:
+                xq, xsc = xch, None
+            g = kops.fused_unpack_matmul(xq, regroup_idx,
+                                         w_gate.astype(ctype), scales=xsc)
+            u = kops.fused_unpack_matmul(xq, regroup_idx,
+                                         w_up.astype(ctype), scales=xsc)
+            a = jax.nn.silu(g) * u
+            h = jnp.einsum("ecf,efd->ecd", a, w_down.astype(ctype))
+        with jax.named_scope("moe/combine"):
+            back = h.reshape(e_loc, ep, ck, d).transpose(1, 0, 2, 3)
+            back = to_wire(back.reshape(ep * e_loc * ck, d).astype(ctype))
+            out = exchange(back) if exchange is not None else back
+            return out.reshape(ep, e_loc, ck, dw)
 
     # Software pipeline: issue chunk c+1's dispatch before chunk c's FFN.
     dispatched = [None] * n_chunks
@@ -486,36 +510,41 @@ def _a2a_shard_body(tokens, router_w, w_gate, w_up, w_down,
     # combine: gather my entries back out of the returned buckets; under a
     # codec the gather reads narrow wire rows and dequant follows it (on
     # [T*k, D] gathered entries, never on the full bucket buffer).
-    padded = jnp.concatenate([returned, jnp.zeros((8, dw), returned.dtype)],
-                             axis=0)
-    ent = padded[slot]
-    comb = keep.astype(ctype) * w.astype(ctype)
-    if codec is not None:
-        if lanes:
-            # Fold the per-row dequant scale into the combine weight: one
-            # [T*k] product instead of a second full-width [T*k, D] pass.
-            eq, esc = wirecodec.split_rows(ent, lanes)
-            ent, comb = eq.astype(ctype), comb * esc.reshape(-1).astype(ctype)
-        else:
-            ent = codec.decode(ent, None, ctype)
-    out_entries = ent * comb[:, None]
-    y_chunk = out_entries.reshape(t_loc, plan.top_k, d).sum(axis=1)
+    with jax.named_scope("moe/combine"):
+        padded = jnp.concatenate(
+            [returned, jnp.zeros((8, dw), returned.dtype)], axis=0)
+        ent = padded[slot]
+        comb = keep.astype(ctype) * w.astype(ctype)
+        if codec is not None:
+            if lanes:
+                # Fold the per-row dequant scale into the combine weight:
+                # one [T*k] product instead of a second full-width [T*k, D]
+                # pass.
+                eq, esc = wirecodec.split_rows(ent, lanes)
+                ent = eq.astype(ctype)
+                comb = comb * esc.reshape(-1).astype(ctype)
+            else:
+                ent = codec.decode(ent, None, ctype)
+        out_entries = ent * comb[:, None]
+        y_chunk = out_entries.reshape(t_loc, plan.top_k, d).sum(axis=1)
 
-    if axis:
-        # Gather-then-slice is the minimal form here, not an oversight: the
-        # slice bound t_have IS host-static (token shapes are trace-time
-        # constants), but XLA collectives move uniform per-rank shapes, so
-        # any "gather only t_have rows" schedule still ships a full
-        # t_loc-row bucket from every rank — an allgatherv plan with ragged
-        # tail counts would set capacity = max(counts) = t_loc and
-        # re-materialize the same [EP * t_loc] wire buffer inside unpack.
-        # The spill is < EP rows of routing padding, truncated before any
-        # consumer sees it.  Semantics pinned by the moe_ragged_tail_combine
-        # dist case.
-        y = jax.lax.all_gather(y_chunk, axis, axis=0, tiled=True)[:t_have]
-    else:
-        y = y_chunk[:t_have]
-    aux_arr = jnp.stack(aux)
+        if axis:
+            # Gather-then-slice is the minimal form here, not an oversight:
+            # the slice bound t_have IS host-static (token shapes are
+            # trace-time constants), but XLA collectives move uniform
+            # per-rank shapes, so any "gather only t_have rows" schedule
+            # still ships a full t_loc-row bucket from every rank — an
+            # allgatherv plan with ragged tail counts would set capacity =
+            # max(counts) = t_loc and re-materialize the same [EP * t_loc]
+            # wire buffer inside unpack.  The spill is < EP rows of routing
+            # padding, truncated before any consumer sees it.  Semantics
+            # pinned by the moe_ragged_tail_combine dist case.
+            y = jax.lax.all_gather(y_chunk, axis, axis=0, tiled=True)[:t_have]
+        else:
+            y = y_chunk[:t_have]
+    # Experts touched by this shard's routing group; the pmean below makes
+    # it the mean over groups.
+    aux_arr = jnp.stack(aux + (_experts_touched(counts),))
     if mesh_axes:
         aux_arr = jax.lax.pmean(aux_arr, axis_name=mesh_axes)
     return y, aux_arr
@@ -526,17 +555,23 @@ def _gspmd_dispatch(x2d, nvalid, params, moe: MoEConfig, plan: MoEDispatchPlan):
     t, d = x2d.shape
     e, cap_total = moe.n_experts, plan.capacity * plan.ep_size
     valid = jnp.arange(t) < nvalid
-    slot, keep, w, _, aux = _route(x2d, params["router"].astype(x2d.dtype),
-                                   valid, moe.top_k, e, cap_total)
-    buckets = _scatter_buckets(x2d, slot, keep, moe.top_k, e * cap_total, d)
-    buckets = cs(buckets.reshape(e, cap_total, d), "experts", None, "embed")
-    h = _expert_ffn(buckets, params["w_gate"], params["w_up"], params["w_down"])
-    # Combine gathers back out of h with *token*-sharded indices.
-    h = h.reshape(e * cap_total, d)
-    padded = jnp.concatenate([h, jnp.zeros((8, d), h.dtype)], axis=0)
-    out = padded[slot] * (keep.astype(h.dtype) * w.astype(h.dtype))[:, None]
-    y = out.reshape(t, moe.top_k, d).sum(axis=1)
-    return y, jnp.stack(aux)
+    with jax.named_scope("moe/router"):
+        slot, keep, w, counts, aux = _route(
+            x2d, params["router"].astype(x2d.dtype), valid, moe.top_k, e,
+            cap_total)
+    with jax.named_scope("moe/dispatch"):
+        buckets = _scatter_buckets(x2d, slot, keep, moe.top_k, e * cap_total, d)
+        buckets = cs(buckets.reshape(e, cap_total, d), "experts", None, "embed")
+    with jax.named_scope("moe/expert_ffn"):
+        h = _expert_ffn(buckets, params["w_gate"], params["w_up"],
+                        params["w_down"])
+    with jax.named_scope("moe/combine"):
+        # Combine gathers back out of h with *token*-sharded indices.
+        h = h.reshape(e * cap_total, d)
+        padded = jnp.concatenate([h, jnp.zeros((8, d), h.dtype)], axis=0)
+        out = padded[slot] * (keep.astype(h.dtype) * w.astype(h.dtype))[:, None]
+        y = out.reshape(t, moe.top_k, d).sum(axis=1)
+    return y, jnp.stack(aux + (_experts_touched(counts),))
 
 
 # ---------------------------------------------------------------------------
@@ -546,7 +581,7 @@ def _gspmd_dispatch(x2d, nvalid, params, moe: MoEConfig, plan: MoEDispatchPlan):
 
 def apply_moe(params: dict, x: jax.Array, moe: MoEConfig,
               plan: Optional[MoEDispatchPlan]) -> tuple[jax.Array, jax.Array]:
-    """x: [B, S, D] -> (y [B, S, D], aux [lb_loss, z_loss])."""
+    """x: [B, S, D] -> (y [B, S, D], aux [lb_loss, z_loss, experts_touched])."""
     b, s, d = x.shape
     x2d = x.reshape(b * s, d)
     mesh = current_mesh()

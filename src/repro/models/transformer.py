@@ -105,19 +105,24 @@ def apply_block(params: dict, cfg: ModelConfig, slot: int, x: jax.Array, *,
                 cache: Optional[dict] = None,
                 cache_index: Optional[jax.Array] = None,
                 causal: bool = True):
-    """Returns (x, aux_losses [2], new_cache)."""
+    """Returns (x, aux [moe.N_AUX], new_cache).
+
+    Device ops run under ``jax.named_scope``: ``attention`` (with the cache
+    write as ``attention/kv_cache``) and the MoE layer's ``moe/*`` stages."""
     kind = cfg.layer_kind(slot)
     rs = cfg.residual_scale
     h = norms.apply_norm(params.get("ln1"), cfg.norm, x)
     new_cache = dict(cache) if cache is not None else None
 
     if kind == "attn":
-        y, kvc = attention.apply_attention(
-            params["attn"], h, n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
-            head_dim=cfg.head_dim, positions=positions, causal=causal,
-            rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm,
-            kv_cache=None if cache is None else {"k": cache["k"], "v": cache["v"]},
-            cache_index=cache_index)
+        with jax.named_scope("attention"):
+            y, kvc = attention.apply_attention(
+                params["attn"], h, n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads,
+                head_dim=cfg.head_dim, positions=positions, causal=causal,
+                rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm,
+                kv_cache=(None if cache is None
+                          else {"k": cache["k"], "v": cache["v"]}),
+                cache_index=cache_index)
         if kvc is not None:
             new_cache.update(kvc)
     elif kind == "mamba":
@@ -154,7 +159,7 @@ def apply_block(params: dict, cfg: ModelConfig, slot: int, x: jax.Array, *,
         raise ValueError(kind)
     x = x + y * rs if rs != 1.0 else x + y
 
-    aux = jnp.zeros((2,), jnp.float32)
+    aux = jnp.zeros((moe.N_AUX,), jnp.float32)
     if cfg.is_moe_layer(slot):
         h = norms.apply_norm(params.get("ln2"), cfg.norm, x)
         y, aux = moe.apply_moe(params["moe"], h, cfg.moe, moe_plan)
@@ -226,7 +231,7 @@ def apply_stack(params: dict, cfg: ModelConfig, x: jax.Array, *,
         h = carry
         slot_params = xs[0]
         slot_caches = xs[1]
-        auxs = jnp.zeros((2,), jnp.float32)
+        auxs = jnp.zeros((moe.N_AUX,), jnp.float32)
         new_caches = []
         for s in range(period):
             def block_fn(p, hh, cc, _s=s):
@@ -256,6 +261,9 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array, *,
             remat: bool = True, return_hidden: bool = False):
     """tokens: [B, S] -> logits [B, S, V_padded] (+ aux, new caches).
 
+    aux: [load-balance loss, router z-loss, experts touched], each summed
+    over the MoE layers (zeros without MoE).
+
     extra_embeds (VLM): [B, N, D_frontend-projected] prepended embeddings.
     decode: pass caches + cache_index (tokens is [B, 1]).
     return_hidden: skip the logits matmul (the loss computes it chunked).
@@ -277,9 +285,10 @@ def forward(params: dict, cfg: ModelConfig, tokens: jax.Array, *,
     x = norms.apply_norm(params.get("ln_f"), cfg.norm, x)
     if return_hidden:
         return x, aux, new_caches
-    logits = embedding.lm_logits(params.get("head"), params["embed"], x,
-                                 cfg.tie_embeddings, cfg.logit_scale,
-                                 valid_vocab=cfg.vocab_size)
+    with jax.named_scope("lm_head"):
+        logits = embedding.lm_logits(params.get("head"), params["embed"], x,
+                                     cfg.tie_embeddings, cfg.logit_scale,
+                                     valid_vocab=cfg.vocab_size)
     return logits, aux, new_caches
 
 

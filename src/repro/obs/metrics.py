@@ -5,7 +5,9 @@ exposition-format text from ``INIT_STATS`` (warm/cold INIT counters, bake
 and burst totals, store hit ratio), ``EXEC_TELEMETRY`` (per-digest epoch
 latency summaries with p50/p95/p99, swap counter), and the break-even
 validator (``repro_breakeven_residual`` per stored fit — the live check
-that a plan's predicted amortization actually materializes).
+that a plan's predicted amortization actually materializes), and the
+serving counters of ``obs.counters`` (decode steps and experts touched:
+the operator's view of expert load).
 ``write_metrics(path)`` snapshots it to a file (the ``--metrics-file``
 flag on the launchers); ``MetricsServer`` serves it over HTTP on a daemon
 thread (the ``--metrics-port`` flag on ``launch/serve.py``) so a scraper
@@ -23,6 +25,7 @@ import threading
 from ..core._exec_stats import EXEC_TELEMETRY
 from ..core._init_stats import INIT_STATS
 from .breakeven_check import check_breakeven
+from .counters import COUNTERS
 
 
 def _line(out: list[str], name: str, value, labels: dict | None = None) -> None:
@@ -106,6 +109,16 @@ def render_metrics(exec_snapshot: dict | None = None,
             if s.get("count"):
                 _line(out, "repro_epoch_rank_seconds", f"{s['p50_s']:.9f}",
                       {"digest": digest, "rank": rank})
+
+    served = COUNTERS.snapshot()
+    out.append("# HELP repro_serve_decode_steps_total Decode steps run by ServeEngine.generate.")
+    out.append("# TYPE repro_serve_decode_steps_total counter")
+    _line(out, "repro_serve_decode_steps_total", served.get("serve.decode_steps", 0))
+
+    out.append("# HELP repro_serve_experts_touched_total Experts with a kept assignment, summed over MoE layers and decode steps (divide by decode steps x MoE layers for experts per layer-step).")
+    out.append("# TYPE repro_serve_experts_touched_total counter")
+    _line(out, "repro_serve_experts_touched_total",
+          served.get("serve.experts_touched", 0))
 
     residuals = check_breakeven(ex)
     if residuals:

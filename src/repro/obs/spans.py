@@ -20,24 +20,34 @@ Span taxonomy (the categories the exporters and the trace validator key on):
   ``init.autotune``  ``variant="auto"`` sweeps and their measurement bursts
   ``store``          plan-store get/put/CAS-merge, attributed with backend
                      root and hit/miss outcome
-  ``execute``        epoch dispatch / recorded epochs / train steps /
-                     serve prefill+decode
+  ``execute``        ``plan.start`` / ``plan.wait``, recorded epochs,
+                     train steps, and the ``serve.*`` host steps of
+                     ``ServeEngine.generate``
   ``runtime``        re-plan triggers, hot-swaps, recovery, chaos
                      injections, elastic resharding (mostly instants)
 
 Hot-path discipline
 -------------------
 
-Tracing is **off by default**: every instrumentation site guards on
-``TRACER.enabled`` (one attribute load) and the disabled cost is just that
-check.  Enabled, a finished span is one tuple stored into a slot of a
-**preallocated ring** — the same storage discipline as
+Every ``TRACER.span`` also enters a ``jax.profiler.TraceAnnotation`` of
+its name, so whenever a profiler session is running (``jax.profiler.trace``,
+an xprof capture, the chip benchmark's ``--trace 1`` run) the span shows on
+the calling thread's host line, on the same clock as the device ops.  The
+annotation gets the name only, never the args: with no profiler running
+and the ring off, a span costs one annotation enter/exit (well under a
+microsecond).
+
+The ring is **off by default**: ``TRACER.enabled`` (one attribute load)
+guards every record.  Enabled, a finished span is one tuple stored into a
+slot of a **preallocated ring** — the same storage discipline as
 ``core._exec_stats.EpochRing``: no locks on the record path (the slot
 index comes from an ``itertools.count``, whose ``next`` is atomic under
 the GIL, so concurrent writers — the re-plan background thread and the
 step loop — never tear a record; a full ring overwrites oldest-first).
-The measured overhead contract lives in ``benchmarks/resilience.py``
-(``steady_traced`` row): tracing on must stay within ~2% of a bare epoch.
+``emit_span`` and ``instant`` record into the ring only: they never reach
+the profiler.  The measured overhead contract lives in
+``benchmarks/resilience.py`` (``steady_traced`` row): tracing on must stay
+within ~2% of a bare epoch.
 """
 
 from __future__ import annotations
@@ -45,6 +55,8 @@ from __future__ import annotations
 import itertools
 import threading
 import time
+
+from jax.profiler import TraceAnnotation
 
 DEFAULT_SPAN_CAPACITY = 1 << 16
 
@@ -72,14 +84,6 @@ class SpanBuffer:
     def emit(self, rec: tuple) -> None:
         self._slots[next(self._idx) % self.capacity] = rec
 
-    @property
-    def count(self) -> int:
-        """Records emitted so far (approximate upper bound of retained)."""
-        # count objects expose their next value via repr only; probing would
-        # consume it.  Track via a non-consuming scan instead: cheap at
-        # snapshot time, and emit() stays free of bookkeeping.
-        return sum(1 for s in self._slots if s is not None)
-
     def snapshot(self) -> list[tuple]:
         """Retained records, oldest-first by timestamp."""
         recs = [s for s in self._slots if s is not None]
@@ -88,10 +92,11 @@ class SpanBuffer:
 
 
 class _SpanCtx:
-    """Context manager for one span; ``.args`` is mutable until exit, so a
-    body can attach outcomes (warm/hit/variant) it only knows at the end."""
+    """Context manager for one recorded span; ``.args`` is mutable until
+    exit, so a body can attach outcomes (warm/hit/variant) it only knows at
+    the end."""
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "args", "_t0", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, args: dict):
         self._tracer = tracer
@@ -100,38 +105,33 @@ class _SpanCtx:
         self.args = args
 
     def __enter__(self) -> "_SpanCtx":
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
         t1 = time.perf_counter()
+        self._annotation.__exit__(exc_type, exc, tb)
         if exc is not None:
             self.args["error"] = repr(exc)
         self._tracer._emit(self.name, self.cat, COMPLETE,
                            self._t0, t1 - self._t0, self.args)
 
 
-class _NullCtx:
-    """Shared no-op context: ``TRACER.span`` returns this when disabled so
-    call sites pay one attribute check and zero allocation."""
+class _Annotation(TraceAnnotation):
+    """A span with the ring off: the profiler annotation alone.  ``args``
+    is a shared scratch dict, so bodies that attach outcomes need no
+    branch on ``TRACER.enabled``."""
 
-    __slots__ = ()
     args: dict = {}
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc) -> None:
-        pass
-
-
-_NULL = _NullCtx()
 
 
 class Tracer:
     """Process-global span recorder (singleton ``TRACER``).
 
-    ``enable(capacity)`` arms it; until then every API is a cheap no-op.
+    ``enable(capacity)`` arms the ring; until then spans are profiler
+    annotations only and nothing is recorded.
     Timestamps are ``perf_counter`` seconds relative to the enable call
     (``origin_unix`` maps them back to wall time for exporters)."""
 
@@ -161,18 +161,21 @@ class Tracer:
             self._thread_names.clear()
 
     # -- recording -----------------------------------------------------------
-    def span(self, name: str, cat: str, **args) -> "_SpanCtx | _NullCtx":
-        """``with TRACER.span("table_bake", "init.bake", p=64): ...``"""
+    def span(self, name: str, cat: str, **args) -> "_SpanCtx | _Annotation":
+        """``with TRACER.span("table_bake", "init.bake", p=64): ...``
+
+        Always a ``jax.profiler.TraceAnnotation(name)``; also recorded in
+        the ring, with ``args``, when the tracer is enabled."""
         if not self.enabled:
-            return _NULL
+            return _Annotation(name)
         return _SpanCtx(self, name, cat, args)
 
     def emit_span(self, name: str, cat: str, t0: float, t1: float,
                   args: dict | None = None) -> None:
         """Record an already-timed interval (``t0``/``t1`` are
-        ``perf_counter`` readings).  The epoch hot path uses this — it
-        already timed itself for the telemetry ring, so the span costs one
-        tuple store, no context manager."""
+        ``perf_counter`` readings) into the ring.  Ring only: the interval
+        is over by the time it is known, so it never reaches a profiler
+        trace — use ``span`` for anything a profiler should see."""
         if self.enabled:
             self._emit(name, cat, COMPLETE, t0, t1 - t0, args)
 

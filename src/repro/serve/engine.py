@@ -1,7 +1,16 @@
 """Batched serving engine: prefill -> decode with persistent caches.
 
 The decode step is the jitted bundle (caches donated, so the KV buffers are
-reused epoch-over-epoch just like the paper's persistent windows)."""
+reused epoch-over-epoch just like the paper's persistent windows).
+
+``generate`` runs each host step under a ``TRACER`` span, so a
+``jax.profiler`` capture shows them on the caller's thread on the device
+trace's clock: ``serve.generate`` holds ``serve.prefill`` (dispatch and
+block), ``serve.grow_caches``, ``serve.first_token`` (argmax and the first
+fetch), then per decode token ``serve.decode_dispatch`` and
+``serve.token_fetch``.  Each call adds its decode steps and the experts its
+steps touched to the process-wide ``serve.decode_steps`` and
+``serve.experts_touched`` counters (``repro.obs.counters``)."""
 
 from __future__ import annotations
 
@@ -12,12 +21,14 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.ckpt.reshard import put_tree
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.launch import steps as steps_mod
 from repro.models import api as model_api
 from repro.models import transformer, whisper
+from repro.obs.counters import COUNTERS
 from repro.obs.spans import TRACER
 
 
@@ -70,6 +81,9 @@ class ServeEngine:
         # decode bundle's plan-backed MoE dispatch plan, built above after
         # the store was configured, so its INIT saw the warm tier.
         self.moe_plan = self.decode_bundle.meta.get("moe_plan")
+        # The decode steps' running experts-touched total starts here.
+        self._no_experts = jax.device_put(
+            np.float32(0), NamedSharding(mesh, PartitionSpec()))
         with self.decode_bundle.trace_context():
             shardings = self.decode_bundle.meta["param_shardings"]
             if params is None:
@@ -88,51 +102,47 @@ class ServeEngine:
                 f"prompt_len {prompt_len} + n_tokens {n_tokens} exceeds "
                 f"max_seq {self.max_seq}: decode would write past the KV "
                 f"caches — raise max_seq or generate fewer tokens")
-        t0 = time.perf_counter()
-        with self.prefill_bundle.trace_context():
-            if cfg.family == "audio":
-                logits, caches = self.prefill_bundle.jitted(
-                    self.params, jnp.asarray(frames), jnp.asarray(prompts))
-            else:
-                logits, caches = self.prefill_bundle.jitted(
-                    self.params, jnp.asarray(prompts))
-        jax.block_until_ready(logits)
-        t_prefill = time.perf_counter() - t0
-        if TRACER.enabled:
-            TRACER.emit_span("prefill", "execute", t0, t0 + t_prefill,
-                             {"batch": self.batch, "prompt_len": prompt_len})
+        with TRACER.span("serve.generate", "execute"):
+            with TRACER.span("serve.prefill", "execute"):
+                t0 = time.perf_counter()
+                with self.prefill_bundle.trace_context():
+                    if cfg.family == "audio":
+                        logits, caches = self.prefill_bundle.jitted(
+                            self.params, jnp.asarray(frames),
+                            jnp.asarray(prompts))
+                    else:
+                        logits, caches = self.prefill_bundle.jitted(
+                            self.params, jnp.asarray(prompts))
+                jax.block_until_ready(logits)
+                t_prefill = time.perf_counter() - t0
 
-        # prefill caches were sized for the prompt; decode caches are sized
-        # max_seq — copy the primed prefix in.
-        caches = self._grow_caches(caches)
-        next_tok = jnp.argmax(logits.astype(jnp.float32), -1).astype(jnp.int32)[:, None]
-        out = [np.asarray(next_tok)]
-        index = prompts.shape[1]
+            # prefill caches were sized for the prompt; decode caches are
+            # sized max_seq — copy the primed prefix in.
+            with TRACER.span("serve.grow_caches", "execute"):
+                caches = self._grow_caches(caches)
+            with TRACER.span("serve.first_token", "execute"):
+                next_tok = jnp.argmax(logits.astype(jnp.float32),
+                                      -1).astype(jnp.int32)[:, None]
+                out = [np.asarray(next_tok)]
+            index = prompts.shape[1]
 
-        t0 = time.perf_counter()
-        with self.decode_bundle.trace_context():
-            for i in range(n_tokens - 1):
-                next_tok, caches = self.decode_bundle.jitted(
-                    self.params, caches, next_tok, jnp.int32(index + i))
-                out.append(np.asarray(next_tok))
-        jax.block_until_ready(next_tok)
-        t1 = time.perf_counter()
-        t_decode = (t1 - t0) / max(n_tokens - 1, 1)
-        if TRACER.enabled:
-            TRACER.emit_span("decode", "execute", t0, t1,
-                             {"batch": self.batch, "tokens": n_tokens,
-                              "seconds_per_token": t_decode})
+            touched = self._no_experts
+            t0 = time.perf_counter()
+            with self.decode_bundle.trace_context():
+                for i in range(n_tokens - 1):
+                    with TRACER.span("serve.decode_dispatch", "execute"):
+                        next_tok, caches, touched = self.decode_bundle.jitted(
+                            self.params, caches, next_tok,
+                            jnp.int32(index + i), touched)
+                    with TRACER.span("serve.token_fetch", "execute"):
+                        out.append(np.asarray(next_tok))
+            t1 = time.perf_counter()
+            t_decode = (t1 - t0) / max(n_tokens - 1, 1)
+            if n_tokens > 1:
+                COUNTERS.add("serve.decode_steps", n_tokens - 1)
+                COUNTERS.add("serve.experts_touched", round(float(touched)))
         tokens = np.concatenate(out, axis=1)
         return tokens, ServeStats(t_prefill, t_decode, tokens.size)
-
-    def metrics_text(self) -> str:
-        """Prometheus text snapshot of the process-global observability
-        state as seen from this engine: INIT counters (warm/cold, store
-        hit ratio for the plan store this replica warmed from), epoch
-        latency summaries for ``self.moe_plan``'s digest, and break-even
-        residuals.  The ``--metrics-port`` endpoint serves the same text."""
-        from repro.obs.metrics import render_metrics
-        return render_metrics()
 
     def _grow_caches(self, prefill_caches):
         """Pad prefill-sized caches out to the decode bundle's cache shapes."""

@@ -2097,6 +2097,47 @@ def obs_trace_contract():
           "worst_rank:", worst)
 
 
+@case
+def a2a_epoch_scopes():
+    """An alltoallv epoch names its stages for the profiler: the standalone
+    executable and an embedded epoch compile with ``a2a/pack``,
+    ``a2a/exchange`` and ``a2a/unpack`` in their ops' ``op_name``."""
+    import re
+
+    from repro.core import PlanCache, alltoallv_init
+    from repro.core.plan import A2A_EXCHANGE, A2A_PACK, A2A_UNPACK
+    from repro.launch.mesh import make_host_mesh
+
+    p = len(jax.devices())
+    counts, bufs, expect, rc, send_rows, recv_rows = _setup_pattern(p, seed=5)
+    mesh = make_host_mesh(p)
+    plan = alltoallv_init(counts, (4,), jnp.float32, mesh, axis="x",
+                          variant="fence", cache=PlanCache())
+    assert not plan.identity_maps          # irregular: both gathers run
+
+    def scopes(hlo_text):
+        names = re.findall(r'op_name="([^"]*)"', hlo_text)
+        return {sc for sc in (A2A_PACK, A2A_EXCHANGE, A2A_UNPACK)
+                if any(sc + "/" in n for n in names)}
+
+    want = {A2A_PACK, A2A_EXCHANGE, A2A_UNPACK}
+    standalone = scopes(plan.compile()._compiled.as_text())
+    assert standalone == want, standalone
+
+    spec = plan._x_sharding.spec
+    body = shard_map(plan.embed(), mesh=mesh, in_specs=spec, out_specs=spec,
+                     check_vma=False)
+    x = jax.ShapeDtypeStruct((p * plan.send_rows, 4), jnp.float32,
+                             sharding=plan._x_sharding)
+    embedded = scopes(jax.jit(body).lower(x).compile().as_text())
+    assert embedded == want, embedded
+
+    x = jax.device_put(jnp.asarray(bufs.reshape(p * send_rows, 4)),
+                       plan._x_sharding)
+    got = np.asarray(plan.wait(plan.start(x)))
+    _check(got.reshape(p, recv_rows, 4), expect, rc, p)
+
+
 def main():
     import argparse
     ap = argparse.ArgumentParser()

@@ -37,8 +37,9 @@ def test_tracer_disabled_is_noop():
     TRACER.instant("y", "runtime")
     TRACER.emit_span("z", "execute", 0.0, 1.0)
     assert TRACER.snapshot()["records"] == []
-    # the disabled context is a shared singleton: zero allocation per call
-    assert TRACER.span("a", "init") is TRACER.span("b", "store")
+    # with the ring off a span is the profiler annotation alone
+    import jax
+    assert isinstance(TRACER.span("a", "init"), jax.profiler.TraceAnnotation)
 
 
 def test_span_records_args_and_outcome_mutation():
@@ -76,7 +77,6 @@ def test_span_buffer_ring_overwrites_oldest():
     buf = SpanBuffer(capacity=8)
     for i in range(20):
         buf.emit(("s", "execute", COMPLETE, float(i), 0.0, 1, None))
-    assert buf.count == 8
     kept = [r[3] for r in buf.snapshot()]
     assert kept == [float(i) for i in range(12, 20)]
 
@@ -449,8 +449,9 @@ def test_obs_cli_metrics_out(tmp_path, capsys):
 # --- plan-level wiring --------------------------------------------------------
 
 def test_plan_epoch_spans_and_record_epoch_anchor():
-    """A plan's start() emits epoch spans when tracing is on, and
-    record_epoch(t_end=...) anchors the backdated span exactly."""
+    """A plan's start() and wait() emit plan.start / plan.wait spans when
+    tracing is on, and record_epoch(t_end=...) anchors the backdated epoch
+    span exactly."""
     import jax
     import jax.numpy as jnp
 
@@ -469,9 +470,14 @@ def test_plan_epoch_spans_and_record_epoch_anchor():
     jax.block_until_ready(plan.wait(plan.start(x)))
     t_end = _time.perf_counter()
     plan.record_epoch(0.25, t_end=t_end)
-    recs = [r for r in TRACER.snapshot()["records"] if r[0] == "epoch"]
-    assert len(recs) == 2
-    anchored = max(recs, key=lambda r: r[3] + r[4])    # latest end = ours
+    recs = TRACER.snapshot()["records"]
+    steps = [r for r in recs if r[0].startswith("plan.")]
+    assert [(r[0], r[1]) for r in steps] == [("plan.start", "execute"),
+                                             ("plan.wait", "execute")]
+    assert steps[0][6]["digest"] == plan.signature.digest
+    recs = [r for r in recs if r[0] == "epoch"]
+    assert len(recs) == 1
+    anchored = recs[0]
     assert anchored[3] + anchored[4] == pytest.approx(t_end - TRACER._t0)
     assert anchored[4] == pytest.approx(0.25)
     assert anchored[6]["digest"] == plan.signature.digest
