@@ -18,7 +18,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from repro.parallel.sharding import ScopedFactory, cs, normal_init
+from repro.parallel.sharding import (ScopedFactory, cs, normal_init,
+                                     pin_default_layout)
 
 FLASH_THRESHOLD = 4096
 Q_CHUNK = 512
@@ -210,7 +211,14 @@ def apply_attention(
     kv_positions: Optional[jax.Array] = None,
     kv_cache: Optional[dict] = None,   # {"k","v": [B, S_max, N_kv, dh]}
     cache_index: Optional[jax.Array] = None,  # scalar write offset
+    cache_layer: Optional[jax.Array] = None,  # layer of a stacked kv_cache
 ) -> tuple[jax.Array, Optional[dict]]:
+    """Returns (y, new_cache).
+
+    With ``cache_layer``, ``kv_cache`` holds every layer's K/V stacked
+    ``[n_rep, B, S_max, N_kv, dh]``: only this layer's new rows are written
+    into the stack (in place when the stack is a scan carry or a donated
+    buffer), and the scores read this layer's K/V back from it."""
     b, s, _ = x.shape
     g = n_heads // n_kv
     scale = head_dim ** -0.5
@@ -236,6 +244,23 @@ def apply_attention(
         k, v = kv_cache["k"], kv_cache["v"]
         k_pos = jnp.broadcast_to(jnp.arange(k.shape[1])[None], (b, k.shape[1]))
         new_cache = kv_cache
+    elif kv_cache is not None and cache_layer is not None:
+        with jax.named_scope("kv_cache"):
+            at = (cache_layer, 0, cache_index, 0, 0)
+            sk = jax.lax.dynamic_update_slice(kv_cache["k"], k[None], at)
+            sv = jax.lax.dynamic_update_slice(kv_cache["v"], v[None], at)
+            # Keep the stack in its layout as the step's argument: left
+            # free, the compiler gives the layer loop the layout the scores
+            # prefer and relayouts the whole cache into and out of it.
+            axes = ("stack", "batch", "seq", "kv_heads", "head_dim")
+            sk = pin_default_layout(cs(sk, *axes), *axes)
+            sv = pin_default_layout(cs(sv, *axes), *axes)
+            k = jax.lax.dynamic_index_in_dim(sk, cache_layer, keepdims=False)
+            v = jax.lax.dynamic_index_in_dim(sv, cache_layer, keepdims=False)
+            k = cs(k, "batch", "seq", "kv_heads", "head_dim")
+            v = cs(v, "batch", "seq", "kv_heads", "head_dim")
+        new_cache = {"k": sk, "v": sv}
+        k_pos = jnp.broadcast_to(jnp.arange(k.shape[1])[None], (b, k.shape[1]))
     elif kv_cache is not None:
         with jax.named_scope("kv_cache"):
             ck = jax.lax.dynamic_update_slice_in_dim(kv_cache["k"], k,

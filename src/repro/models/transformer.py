@@ -104,8 +104,12 @@ def apply_block(params: dict, cfg: ModelConfig, slot: int, x: jax.Array, *,
                 moe_plan: Optional[moe.MoEDispatchPlan],
                 cache: Optional[dict] = None,
                 cache_index: Optional[jax.Array] = None,
+                cache_layer: Optional[jax.Array] = None,
                 causal: bool = True):
     """Returns (x, aux [moe.N_AUX], new_cache).
+
+    ``cache_layer``: an attention slot's ``cache`` is the whole stack of its
+    layers' K/V, and this block is layer ``cache_layer`` of it.
 
     Device ops run under ``jax.named_scope``: ``attention`` (with the cache
     write as ``attention/kv_cache``) and the MoE layer's ``moe/*`` stages."""
@@ -122,7 +126,7 @@ def apply_block(params: dict, cfg: ModelConfig, slot: int, x: jax.Array, *,
                 rope_theta=cfg.rope_theta, qk_norm=cfg.qk_norm,
                 kv_cache=(None if cache is None
                           else {"k": cache["k"], "v": cache["v"]}),
-                cache_index=cache_index)
+                cache_index=cache_index, cache_layer=cache_layer)
         if kvc is not None:
             new_cache.update(kvc)
     elif kind == "mamba":
@@ -223,36 +227,57 @@ def apply_stack(params: dict, cfg: ModelConfig, x: jax.Array, *,
                 cache_index: Optional[jax.Array] = None,
                 causal: bool = True,
                 remat: bool = True):
-    """Scan the periodic stack. caches: per-slot stacked pytrees or None."""
+    """Scan the periodic stack. caches: per-slot stacked pytrees or None.
+
+    With ``cache_index`` (prefill and decode), each attention slot's stacked
+    K/V rides in the scan carry and every layer writes only its new rows
+    into it, so a donated cache is updated in place.  Recurrent states
+    (mamba, mLSTM, sLSTM) are replaced whole each step: they stay scan
+    inputs and outputs."""
     period = layer_period(cfg)
     slots = _stack_params(params, cfg)
+    n_rep = cfg.n_layers // period
+    carried = [caches is not None and cache_index is not None
+               and cfg.layer_kind(s) == "attn" for s in range(period)]
 
     def body(carry, xs):
-        h = carry
-        slot_params = xs[0]
-        slot_caches = xs[1]
+        h, kv = carry
+        slot_params, layer, slot_caches = xs
         auxs = jnp.zeros((moe.N_AUX,), jnp.float32)
+        kv = list(kv)
         new_caches = []
         for s in range(period):
             def block_fn(p, hh, cc, _s=s):
                 return apply_block(p, cfg, _s, hh, positions=positions,
                                    moe_plan=moe_plan, cache=cc,
-                                   cache_index=cache_index, causal=causal)
+                                   cache_index=cache_index,
+                                   cache_layer=layer if carried[_s] else None,
+                                   causal=causal)
             if remat and period > 1:
                 # nested remat: a multi-layer period (jamba's 8) must not
                 # keep all its layers' backward transients live at once
                 block_fn = jax.checkpoint(block_fn)
-            h, aux, nc = block_fn(
-                slot_params[s], h,
-                None if slot_caches is None else slot_caches[s])
+            if carried[s]:
+                h, aux, kv[s] = block_fn(slot_params[s], h, kv[s])
+                new_caches.append(None)
+            else:
+                h, aux, nc = block_fn(
+                    slot_params[s], h,
+                    None if slot_caches is None else slot_caches[s])
+                new_caches.append(nc)
             auxs = auxs + aux
-            new_caches.append(nc)
-        return h, (auxs, new_caches if caches is not None else 0)
+        return (h, kv), (auxs, new_caches if caches is not None else 0)
 
-    xs = (slots, caches if caches is not None else None)
-    n_rep = cfg.n_layers // period
-    x, (auxs, new_caches) = scan_blocks(body, x, xs, n_rep, remat=remat)
-    return x, auxs.sum(axis=0), (new_caches if caches is not None else None)
+    kv = [c if carried[s] else None for s, c in enumerate(caches or [])]
+    scanned = (None if caches is None else
+               [None if carried[s] else c for s, c in enumerate(caches)])
+    xs = (slots, jnp.arange(n_rep), scanned)
+    (x, kv), (auxs, new_caches) = scan_blocks(body, (x, kv), xs, n_rep,
+                                              remat=remat)
+    if caches is None:
+        return x, auxs.sum(axis=0), None
+    return x, auxs.sum(axis=0), [kv[s] if carried[s] else c
+                                 for s, c in enumerate(new_caches)]
 
 
 def forward(params: dict, cfg: ModelConfig, tokens: jax.Array, *,

@@ -18,6 +18,7 @@ from typing import Callable, Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 # Physical axes of the production mesh (launch/mesh.py):
@@ -195,6 +196,26 @@ def cs(x: jax.Array, *logical_axes: Optional[str]) -> jax.Array:
         return x
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(mesh, resolve(logical_axes, x.shape)))
+
+
+def pin_default_layout(x: jax.Array, *logical_axes: Optional[str]) -> jax.Array:
+    """Constrain each shard of ``x`` to the layout its device gives an array
+    of that shape by default, which is the layout of a jitted step's
+    arguments and results (no-op without a mesh).  Over several devices the
+    constraint is applied shard by shard: the partitioner knows no rule for
+    it and would gather the whole array first."""
+    mesh = _CTX.mesh
+    if mesh is None:
+        return x
+    spec = resolve(logical_axes, x.shape)
+    device = mesh.devices.flat[0]
+    shard = NamedSharding(mesh, spec).shard_shape(x.shape)
+    layout = Layout.from_pjrt_layout(
+        device.client.get_default_layout(x.dtype, shard, device))
+    pin = lambda a: with_layout_constraint(a, layout)  # noqa: E731
+    if mesh.devices.size == 1:
+        return pin(x)
+    return jax.shard_map(pin, mesh=mesh, in_specs=spec, out_specs=spec)(x)
 
 
 # ---------------------------------------------------------------------------

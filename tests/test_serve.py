@@ -9,7 +9,7 @@ import pytest
 
 from repro.configs import get_reduced
 from repro.launch.mesh import make_mesh
-from repro.models import api as model_api, transformer
+from repro.models import api as model_api, attention, embedding, norms, transformer
 from repro.parallel.sharding import DEFAULT_RULES, axis_rules
 from repro.serve import ServeEngine
 
@@ -43,6 +43,62 @@ def test_decode_matches_forward(arch):
     oracle = np.asarray(seq[:, 16:])
     np.testing.assert_array_equal(toks, oracle)
     assert stats.tokens_generated == 12
+
+
+def _uncached_kv(params, cfg, tokens):
+    """Each layer's K/V [L, B, S, N_kv, dh] in an uncached forward over
+    ``tokens``, taken layer by layer (one attention slot per period)."""
+    x = embedding.embed_tokens(params["embed"], tokens, cfg.embed_scale)
+    pos = jnp.broadcast_to(jnp.arange(tokens.shape[1])[None], tokens.shape)
+    ks, vs = [], []
+    for layer in range(cfg.n_layers):
+        p = jax.tree.map(lambda a: a[layer], params["slot0"])
+        h = norms.apply_norm(p.get("ln1"), cfg.norm, x)
+        k = jnp.einsum("btd,dnh->btnh", h, p["attn"]["wk"])
+        v = jnp.einsum("btd,dnh->btnh", h, p["attn"]["wv"])
+        if cfg.qk_norm:
+            k = attention._rms(k, p["attn"]["k_norm"])
+        ks.append(attention.apply_rope(k, pos, cfg.rope_theta))
+        vs.append(v)
+        x, _, _ = transformer.apply_block(p, cfg, 0, x, positions=pos,
+                                          moe_plan=None)
+    return np.stack(ks), np.stack(vs)
+
+
+@pytest.mark.parametrize("arch", ["olmo-1b", "olmoe-1b-7b"])
+def test_decode_cache_holds_forward_kv(arch):
+    """The caches the last decode step returns hold, in rows [0, prompt +
+    n - 1), each layer's K/V of an uncached forward over the prompt and the
+    tokens fed back; the rows past them are still zero."""
+    cfg = _no_drop(get_reduced(arch))
+    assert transformer.layer_period(cfg) == 1
+    mesh = make_mesh((1, 1), ("data", "model"))
+    eng = ServeEngine(cfg, mesh, batch=2, prompt_len=16, max_seq=48, seed=0)
+    step, last = eng.decode_bundle.jitted, {}
+
+    def recording(*args):
+        out = step(*args)
+        last["caches"] = out[1]
+        return out
+
+    eng.decode_bundle.jitted = recording
+    rng = np.random.default_rng(1)
+    prompts = rng.integers(0, cfg.vocab_size, (2, 16)).astype(np.int32)
+    n = 6
+    toks, _ = eng.generate(prompts, n_tokens=n)
+    filled = 16 + n - 1
+    (cache,) = last["caches"]
+
+    with axis_rules(DEFAULT_RULES, mesh):
+        params, _ = model_api.init_model(jax.random.key(0), cfg)
+        seq = jnp.asarray(np.concatenate([prompts, toks[:, :n - 1]], 1))
+        ref = dict(zip("kv", _uncached_kv(params, cfg, seq)))
+    for name in "kv":
+        got = np.asarray(cache[name])
+        assert got.shape == (cfg.n_layers, 2, 48, cfg.n_kv_heads, cfg.head_dim)
+        np.testing.assert_allclose(got[:, :, :filled], ref[name],
+                                   rtol=1e-5, atol=1e-5)
+        assert not got[:, :, filled:].any()
 
 
 def test_whisper_generate_smoke():

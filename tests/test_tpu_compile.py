@@ -1,14 +1,18 @@
-"""Compile the main-path Pallas kernels for a described TPU v5e (2x2).
+"""Compile the main-path Pallas kernels, and the serving decode step, for a
+described TPU v5e (2x2).
 
 Nothing runs here: each test lowers a kernel at real widths and compiles it
 with the TPU compiler for a chip that is described, not attached.  That
 compiler refuses what interpret mode accepts (a one-row slice of a tiled
 ref, VMEM overflow, a collective_id without a barrier semaphore), so these
-tests guard the chip path at no chip time.  The topology is described inside
-a fixture: only the worker that runs this file loads the TPU library.
+tests guard the chip path at no chip time.  The decode step's test reads the
+compiled module: the KV cache must be written in place.  The topology is
+described inside a fixture: only the worker that runs this file loads the
+TPU library.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -119,3 +123,78 @@ def test_hier_leader_exchange_compiles(topo):
             jax.ShapeDtypeStruct((4, sched.total_s2), jnp.int32, sharding=sh),
             jax.ShapeDtypeStruct((4, sched.total_s2), jnp.int32, sharding=sh))
     assert "tpu_custom_call" in _compiled_text(f, *args)
+
+
+# An HLO instruction with an array result: name, element type, dims, opcode,
+# the rest of the line.
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = (\w+)\[([\d,]*)\](?:\{[^}]*\})? "
+                    r"([\w-]+)\((.*)$")
+_HEADER = re.compile(r"^(?:ENTRY )?%(\S+) .*\{$")
+
+
+def _computations(text: str) -> dict:
+    """Optimized HLO text -> {computation name: [instruction matches]}."""
+    comps, name = {}, None
+    for line in text.splitlines():
+        head = _HEADER.match(line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif line.startswith("}"):
+            name = None
+        elif name is not None:
+            m = _INSTR.match(line)
+            if m:
+                comps[name].append((m, line.lstrip().startswith("ROOT ")))
+    return comps
+
+
+@pytest.mark.parametrize("layers", [4, 2])     # lax.scan; unrolled
+def test_decode_step_writes_kv_cache_in_place(topo, layers):
+    """The compiled decode step aliases every donated cache leaf to its
+    output, and no copy, concatenation or other whole-cache write produces
+    an array of a whole stacked cache leaf's shape: the only ops that write
+    one are dynamic-update-slices of the new rows (fused or not)."""
+    from repro.configs import get_reduced
+    from repro.configs.base import ShapeConfig
+    from repro.launch import steps
+
+    cfg = get_reduced("olmoe-1b-7b", n_layers=layers)
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    # a cache large enough to stay in HBM, as a served one does
+    bundle = steps.make_decode_bundle(
+        cfg, ShapeConfig("decode", "decode", 1024, 8), mesh)
+    caches = jax.tree.leaves(bundle.arg_specs[1])
+    stack = {",".join(map(str, c.shape)) for c in caches}
+    assert len(stack) == 1 and caches[0].shape[0] == layers
+    text = bundle.compile().as_text()
+
+    header = text.splitlines()[0]
+    aliased = {int(n) for n in re.findall(r"\{[\d,]*\}: \((\d+), \{", header)}
+    entry = re.search(r"^ENTRY %(\S+) ", text, re.M).group(1)
+    comps = _computations(text)
+    fused = {c for comp in comps.values() for m, _ in comp
+             for c in re.findall(r"calls=%([\w.-]+)", m.group(5))
+             if m.group(4) == "fusion"}
+    roots = {name: next(m.group(4) for m, root in comp if root)
+             for name, comp in comps.items() if name in fused}
+    cache_params, writers = [], []
+    for name, comp in comps.items():
+        if name in fused:
+            continue
+        for m, _ in comp:
+            if m.group(3) not in stack:
+                continue
+            op = m.group(4)
+            if op == "parameter" and name == entry:
+                cache_params.append(int(re.match(r"(\d+)\)", m.group(5))[1]))
+            elif op == "fusion":
+                root = roots[re.search(r"calls=%([\w.-]+)", m.group(5))[1]]
+                if root != "dynamic-update-slice":
+                    writers.append(f"{m.group(1)} (fusion rooted in {root})")
+            elif op not in ("parameter", "get-tuple-element", "bitcast",
+                            "dynamic-update-slice"):
+                writers.append(f"{m.group(1)} ({op})")
+    assert len(cache_params) == len(caches)
+    assert set(cache_params) <= aliased, (cache_params, header)
+    assert not writers, writers
