@@ -59,6 +59,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from jax import shard_map
+from ..obs.counters import COUNTERS
 from ..obs.spans import TRACER
 from ..parallel import wirecodec
 from . import metadata as md
@@ -301,6 +302,7 @@ class ExchangePlan:
 
         row_elems = int(np.prod(spec.feature_shape)) if spec.feature_shape else 1
         row_bytes = row_elems * jnp.dtype(spec.dtype).itemsize
+        self.wire_bytes = self._wire_bytes(sc, row_elems)
         self.signature = md.PatternSignature.build(
             sc, spec.feature_shape, spec.dtype, spec.variant, spec.axis, row_bytes,
             lock_schedule=spec.lock_schedule, tile_rows=spec.tile_rows,
@@ -409,6 +411,32 @@ class ExchangePlan:
                               "warm": self.warm_loaded,
                               "p": self.p,
                               "codec": spec.codec})
+
+    def _wire_bytes(self, sc: np.ndarray, row_elems: int) -> int | None:
+        """Bytes one standalone epoch's collective carries between distinct
+        chips, padding included, summed over the mesh: every off-chip
+        bucket at the fence capacity, each lock round at its own capacity,
+        the exact off-diagonal rows for ragged.  Rows travel at the wire
+        codec's width, plus a 4-byte fp32 scale per row for scaled codecs.
+        None where the plan keeps no closed form (hierarchy schedules,
+        other collective families)."""
+        spec = self.spec
+        if spec.collective != "alltoallv":
+            return None
+        if spec.variant == "fence":
+            rows = self.p * (self.p - 1) * self.capacity
+        elif spec.variant == "lock":
+            rows = self.p * sum(min(int(c), self.capacity)
+                                for c in self.round_capacities[1:])
+        elif spec.variant == "ragged":
+            rows = int(sc.sum() - np.trace(sc))
+        else:
+            return None
+        if spec.codec == "identity":
+            return rows * row_elems * jnp.dtype(spec.dtype).itemsize
+        codec = wirecodec.get(spec.codec)
+        scale = 4 if codec.has_scales else 0
+        return rows * (row_elems * jnp.dtype(codec.wire_dtype).itemsize + scale)
 
     # -- geometry ------------------------------------------------------------
     @property
@@ -790,6 +818,7 @@ class ExchangePlan:
             EXEC_TELEMETRY.record(self._digest, t1 - t0)
         self.window.adopt(out)   # donated-in, aliased-out: window reuse
         self.starts += 1
+        self._count_epoch()
         return out
 
     def start_pipelined(self, sendbuf: jax.Array, depth: int = 2) -> jax.Array:
@@ -817,7 +846,13 @@ class ExchangePlan:
             EXEC_TELEMETRY.record(self._digest, t1 - t0)
         self.window.adopt(out, slot=slot)
         self.starts += 1
+        self._count_epoch()
         return out
+
+    def _count_epoch(self) -> None:
+        COUNTERS.add("a2a.epochs")
+        if self.wire_bytes is not None:
+            COUNTERS.add("a2a.wire_bytes", self.wire_bytes)
 
     @staticmethod
     def wait(recvbuf: jax.Array) -> jax.Array:

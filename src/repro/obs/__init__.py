@@ -12,7 +12,8 @@ live run* instead of only in offline benchmark sweeps:
 - ``trace_export``    Chrome-trace/Perfetto JSON + JSONL exporters and the
                       structural validator CI's ``obs-smoke`` job runs
 - ``counters``        process-wide named counters (``COUNTERS``): decode
-                      steps and experts touched by ``ServeEngine``
+                      steps and experts touched by ``ServeEngine``,
+                      alltoallv epochs and their wire bytes
 - ``metrics``         Prometheus text exposition (+ ``MetricsServer`` for
                       ``--metrics-port``) over INIT counters, epoch rings,
                       swap log, serving counters and break-even residuals

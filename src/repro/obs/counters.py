@@ -1,4 +1,5 @@
-"""Process-wide named counters for what the serving hot path counts.
+"""Process-wide named counters for what the serving and exchange hot paths
+count.
 
 A counter is a name and a running integer total, bumped under a lock (the
 metrics server's scrape thread reads while the serving loop adds).  Names:
@@ -9,6 +10,18 @@ metrics server's scrape thread reads while the serving loop adds).  Names:
                               step (``serve.experts_touched`` /
                               (``serve.decode_steps`` x MoE layers) is the
                               mean experts one layer-step reads)
+  ``a2a.epochs``              standalone alltoallv epochs launched
+                              (``ExchangePlan.start`` and
+                              ``start_pipelined``)
+  ``a2a.wire_bytes``          bytes those epochs' collectives carried
+                              between distinct chips, padding included,
+                              summed over the mesh (``plan.wire_bytes`` per
+                              epoch; plans without a closed form for it,
+                              such as hierarchy schedules, add nothing)
+
+Embedded epochs (``ExchangePlan.embed()`` bodies inside a larger jitted
+program, such as the MoE dispatch) run on the device with no host call per
+epoch, so they bump neither ``a2a.*`` counter.
 
 ``obs.metrics`` renders each as ``repro_<name with _ for .>_total``.
 """

@@ -6,8 +6,9 @@ and burst totals, store hit ratio), ``EXEC_TELEMETRY`` (per-digest epoch
 latency summaries with p50/p95/p99, swap counter), and the break-even
 validator (``repro_breakeven_residual`` per stored fit — the live check
 that a plan's predicted amortization actually materializes), and the
-serving counters of ``obs.counters`` (decode steps and experts touched:
-the operator's view of expert load).
+counters of ``obs.counters`` (decode steps and experts touched: the
+operator's view of expert load; alltoallv epochs and the bytes they put on
+the wire).
 ``write_metrics(path)`` snapshots it to a file (the ``--metrics-file``
 flag on the launchers); ``MetricsServer`` serves it over HTTP on a daemon
 thread (the ``--metrics-port`` flag on ``launch/serve.py``) so a scraper
@@ -110,15 +111,23 @@ def render_metrics(exec_snapshot: dict | None = None,
                 _line(out, "repro_epoch_rank_seconds", f"{s['p50_s']:.9f}",
                       {"digest": digest, "rank": rank})
 
-    served = COUNTERS.snapshot()
+    counted = COUNTERS.snapshot()
     out.append("# HELP repro_serve_decode_steps_total Decode steps run by ServeEngine.generate.")
     out.append("# TYPE repro_serve_decode_steps_total counter")
-    _line(out, "repro_serve_decode_steps_total", served.get("serve.decode_steps", 0))
+    _line(out, "repro_serve_decode_steps_total", counted.get("serve.decode_steps", 0))
 
     out.append("# HELP repro_serve_experts_touched_total Experts with a kept assignment, summed over MoE layers and decode steps (divide by decode steps x MoE layers for experts per layer-step).")
     out.append("# TYPE repro_serve_experts_touched_total counter")
     _line(out, "repro_serve_experts_touched_total",
-          served.get("serve.experts_touched", 0))
+          counted.get("serve.experts_touched", 0))
+
+    out.append("# HELP repro_a2a_epochs_total Standalone alltoallv epochs launched by ExchangePlan.start/start_pipelined.")
+    out.append("# TYPE repro_a2a_epochs_total counter")
+    _line(out, "repro_a2a_epochs_total", counted.get("a2a.epochs", 0))
+
+    out.append("# HELP repro_a2a_wire_bytes_total Bytes those epochs' collectives carried between distinct chips, padding included.")
+    out.append("# TYPE repro_a2a_wire_bytes_total counter")
+    _line(out, "repro_a2a_wire_bytes_total", counted.get("a2a.wire_bytes", 0))
 
     residuals = check_breakeven(ex)
     if residuals:

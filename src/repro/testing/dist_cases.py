@@ -2138,6 +2138,67 @@ def a2a_epoch_scopes():
     _check(got.reshape(p, recv_rows, 4), expect, rc, p)
 
 
+@case
+def a2a_wire_counters():
+    """``a2a.epochs`` and ``a2a.wire_bytes`` count each standalone epoch
+    (``start`` and ``start_pipelined``) and the bytes its collective puts
+    between distinct chips, padding included, as reckoned here from the
+    counts: every off-chip fence bucket at the capacity, each lock round at
+    its diagonal's tile-rounded maximum, the off-diagonal rows for ragged,
+    at the codec's wire width.  An embedded epoch counts nothing."""
+    from repro.core import PlanCache, alltoallv_init, metadata as md
+    from repro.launch.mesh import make_host_mesh
+    from repro.obs.counters import COUNTERS
+
+    p = len(jax.devices())
+    counts, bufs, expect, rc, send_rows, recv_rows = _setup_pattern(p, seed=11)
+    mesh = make_host_mesh(p)
+    row = 4 * 4                                   # (4,) float32
+    tile = md.TILE_ROWS
+    cap = max(md.round_up(int(counts.max()), tile), tile)
+    diag = [int(counts[np.arange(p), (np.arange(p) + r) % p].max())
+            for r in range(1, p)]
+    ring = [max(md.round_up(m, tile), tile) if m else 0 for m in diag]
+    off = int(counts.sum() - np.trace(counts))
+    want = {("fence", "identity"): p * (p - 1) * cap * row,
+            ("lock", "identity"): p * sum(ring) * row,
+            ("ragged", "identity"): off * row,
+            ("fence", "bf16"): p * (p - 1) * cap * 4 * 2,
+            ("fence", "int8"): p * (p - 1) * cap * (4 * 1 + 4)}
+    plans = {}
+    for (variant, codec), wire in want.items():
+        kw = {"codec": codec, "error_tol": 1.0} if codec != "identity" else {}
+        plan = alltoallv_init(counts, (4,), jnp.float32, mesh, axis="x",
+                              variant=variant, cache=PlanCache(), **kw)
+        assert plan.wire_bytes == wire, (variant, codec, plan.wire_bytes, wire)
+        plans[variant, codec] = plan
+    assert 0 < want["ragged", "identity"] < want["fence", "identity"]
+
+    x = jax.device_put(jnp.asarray(bufs.reshape(p * send_rows, 4)),
+                       plans["fence", "identity"]._x_sharding)
+    for variant in ("fence", "lock"):
+        plan = plans[variant, "identity"]
+        before = COUNTERS.snapshot()
+        got = np.asarray(plan.wait(plan.start(x)))
+        _check(got.reshape(p, recv_rows, 4), expect, rc, p)
+        for _ in range(2):
+            plan.wait(plan.start_pipelined(x))
+        after = COUNTERS.snapshot()
+        epochs = after["a2a.epochs"] - before.get("a2a.epochs", 0)
+        wire = after["a2a.wire_bytes"] - before.get("a2a.wire_bytes", 0)
+        assert (epochs, wire) == (3, 3 * want[variant, "identity"]), \
+            (variant, epochs, wire)
+
+    plan = plans["fence", "identity"]
+    spec = plan._x_sharding.spec
+    body = jax.jit(shard_map(plan.embed(), mesh=mesh, in_specs=spec,
+                             out_specs=spec, check_vma=False))
+    before = COUNTERS.snapshot()
+    got = np.asarray(jax.block_until_ready(body(x)))
+    assert COUNTERS.snapshot() == before
+    _check(got.reshape(p, recv_rows, 4), expect, rc, p)
+
+
 def main():
     import argparse
     ap = argparse.ArgumentParser()

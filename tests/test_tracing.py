@@ -106,6 +106,25 @@ def test_generate_counts_decode_steps_and_experts(engine):
             f"{after['serve.experts_touched']}" in text)
 
 
+def test_generate_bumps_no_exchange_counter(engine):
+    eng, prompts = engine
+    before = COUNTERS.snapshot()
+    eng.generate(prompts, TOKENS)
+    after = COUNTERS.snapshot()
+    for name in ("a2a.epochs", "a2a.wire_bytes"):
+        assert after.get(name, 0) == before.get(name, 0), name
+
+
+def test_exchange_counters_render_in_prometheus():
+    COUNTERS.add("a2a.epochs", 2)
+    COUNTERS.add("a2a.wire_bytes", 96)
+    snap = COUNTERS.snapshot()
+    text = render_metrics()
+    assert f"repro_a2a_epochs_total {snap['a2a.epochs']}" in text
+    assert f"repro_a2a_wire_bytes_total {snap['a2a.wire_bytes']}" in text
+    assert "# TYPE repro_a2a_wire_bytes_total counter" in text
+
+
 @pytest.mark.parametrize("capacity_factor", [0.25, 4.0])
 def test_experts_touched_matches_a_numpy_recount(capacity_factor):
     """The third aux entry counts the experts with at least one kept
@@ -156,4 +175,8 @@ def test_step_executables_name_their_layers(kind):
 
 def test_alltoallv_epoch_names_its_stages(dist):
     dist("a2a_epoch_scopes", devices=4)
+
+
+def test_alltoallv_epochs_count_their_wire_bytes(dist):
+    dist("a2a_wire_counters", devices=4)
 
